@@ -179,11 +179,12 @@ def bench_dpp_executor() -> None:
 
     from repro.core.dpp.executor import build_time_table, pipeline_apply
     from repro.core.dpp.schedule import sched_wave
+    from repro.launch.mesh import auto_mesh
 
     S, C, n_micro, B, D = 4, 2, 8, 4, 64
     params = jax.random.normal(jax.random.PRNGKey(0), (S, C, D, D)) * 0.3
     x = jax.random.normal(jax.random.PRNGKey(1), (n_micro, B, D))
-    mesh = jax.make_mesh((S,), ("stage",))
+    mesh = auto_mesh((S,), ("stage",))
     table = build_time_table(sched_wave(n_micro, C, 2), S, C, n_micro)
     fn = jax.jit(lambda p, xx: pipeline_apply(
         p, xx, table, mesh=mesh, block_fn=lambda w, h: jnp.tanh(h @ w)))

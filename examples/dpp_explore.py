@@ -19,6 +19,7 @@ from repro.core.dpp.schedule import sched_wave
 from repro.core.simkit.engine import FaultModel
 from repro.core.simkit.workload import ModelProfile, Topology
 from repro.core.tracing.detect import Diagnosis
+from repro.launch.mesh import auto_mesh
 
 
 def main() -> None:
@@ -52,7 +53,7 @@ def main() -> None:
     key = jax.random.PRNGKey(0)
     params = jax.random.normal(key, (S, C, D, D)) * 0.3
     x = jax.random.normal(jax.random.fold_in(key, 1), (n_micro, B, D))
-    mesh = jax.make_mesh((S,), ("stage",))
+    mesh = auto_mesh((S,), ("stage",))
     for wave, name in ((1, "DFC"), (n_micro, "BFC")):
         table = build_time_table(sched_wave(n_micro, C, wave), S, C, n_micro)
         out = pipeline_apply(params, x, table, mesh=mesh,

@@ -307,6 +307,9 @@ def run(argv: list[str]) -> dict:
 
 
 def main(argv: list[str] | None = None) -> None:
+    from repro.core.compile_cache import use_jax_cache
+
+    use_jax_cache()
     run(sys.argv[1:] if argv is None else list(argv))
 
 

@@ -18,9 +18,11 @@ Modeled on jax's experimental compilation cache, with the same two defenses:
   differing in any of them compile to different XLA modules.
 
 Entries are whole pickled ``jax.experimental.serialize_executable`` triples
-``(payload, in_tree, out_tree)`` behind a small magic header, written
-atomically (tmp + rename) so concurrent processes can share one cache
-directory.  Every read path fails *open*: a missing, truncated, corrupt, or
+``(payload, in_tree, out_tree)``, plus the ids of the devices the executable
+was compiled for (a process with more local devices would otherwise load it
+onto all of them and reject single-device arguments), behind a small magic
+header, written atomically (tmp + rename) so concurrent processes can share
+one cache directory.  Every read path fails *open*: a missing, truncated, corrupt, or
 version-skewed entry returns ``None`` (counted in ``stats.errors`` and
 unlinked when possible) and the caller falls back to a normal compile — the
 cache can only ever make startup faster, never wrong or fatal.
@@ -38,6 +40,27 @@ from pathlib import Path
 from typing import Any, Callable
 
 _MAGIC = b"RPCC"  # repro compile cache
+# the checkout this module runs from (src/repro/core/ -> three levels up)
+_CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def use_jax_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps the cache
+    there and this sets nothing.  Otherwise the cache goes to the fixed path
+    ``<checkout>/.jax_cache`` (never a temp name), so every run of this
+    checkout finds what the last one compiled.  Entry points call this —
+    ``python -m repro`` and ``chip_smoke.py`` — never a module import.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = str(_CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 @dataclass
@@ -136,6 +159,7 @@ class CompileCache:
     def load(self, key: str) -> Callable | None:
         """Deserialize the cached executable for ``key``; ``None`` on miss
         *or any failure* (corrupt/truncated/alien entries are dropped)."""
+        import jax
         from jax.experimental import serialize_executable as se
 
         path = self._path(key)
@@ -147,8 +171,12 @@ class CompileCache:
         try:
             if blob[: len(_MAGIC)] != _MAGIC:
                 raise ValueError("bad magic")
-            payload, in_tree, out_tree = pickle.loads(blob[len(_MAGIC):])
-            fn = se.deserialize_and_load(payload, in_tree, out_tree)
+            payload, in_tree, out_tree, ids = pickle.loads(blob[len(_MAGIC):])
+            by_id = {d.id: d for d in jax.devices()}
+            fn = se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in ids],
+            )
         except Exception:
             # fail open: a corrupt entry must cost one recompile, not a crash
             self.stats.errors += 1
@@ -163,11 +191,16 @@ class CompileCache:
     def put(self, key: str, compiled: Any) -> bool:
         """Serialize ``compiled`` (a ``jax`` Compiled/Loaded executable)
         under ``key``; atomic rename so concurrent writers race benignly."""
+        import jax
         from jax.experimental import serialize_executable as se
 
         try:
             payload, in_tree, out_tree = se.serialize(compiled)
-            blob = _MAGIC + pickle.dumps((payload, in_tree, out_tree))
+            sharding = jax.tree.leaves(
+                (compiled.input_shardings, compiled.output_shardings)
+            )[0]
+            ids = [d.id for d in sharding._device_assignment]
+            blob = _MAGIC + pickle.dumps((payload, in_tree, out_tree, ids))
             d = self._dir()
             d.mkdir(parents=True, exist_ok=True)
             tmp = d / f".{key}.{os.getpid()}.tmp"

@@ -31,17 +31,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core.dpp.schedule import Step
 from repro.core.tracing.events import TraceEvent
 
-# jax moved shard_map out of experimental (and renamed check_rep -> check_vma)
-# around 0.5/0.6; support both so the executor runs on the pinned 0.4.x too.
-try:
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-
-    _SHARD_MAP_KW = {"check_vma": False}
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_rep": False}
-
 
 @dataclass
 class TimeTable:
@@ -232,11 +221,11 @@ def pipeline_apply(
     x_spec = P() if data_axis is None else P(data_axis)
     if param_specs is None:
         param_specs = P(axis)  # broadcast: every leaf stage-sharded only
-    fn = _shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(param_specs, x_spec),
         out_specs=x_spec,
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     return fn(params, x_micro)
 

@@ -19,15 +19,29 @@ Grid ``(slot, table-entry)`` with the table walk innermost/sequential; the
 ``tables[s, j]`` *before* the body runs and each step DMAs exactly one
 physical block out of the pool.  All KV heads of a block are fetched in one
 block (grid iterates table entries, not kv-heads: each block is touched once
-per slot instead of once per head) and the GQA head arithmetic happens
-in-register on the ``[Q, K, G, dh]`` reshaped query.  The Q query rows share
+per slot instead of once per head).  The Q query rows share
 every fetched K/V block: multi-token verification costs the same HBM traffic
 as single-token decode.
 
-Online softmax state (running max / denominator / unnormalized accumulator)
-lives in revisited output blocks whose index maps ignore ``j`` — VMEM-resident
-across the sweep, normalized in place on the last step (the same pattern as
-``flash_attention``).
+TPU layout.  Mosaic lowers only 2-D (or leading-batch) matmuls and wants
+every block's last two dims either (8, 128)-aligned or whole, so the wrapper
+re-lays both sides around the KV head:
+
+* q rides as ``[S, K, Q*G, dh]`` — KV head leading, the ``G`` query heads of
+  that group times the ``Q`` query tokens on the row axis (row ``r`` is query
+  ``r // G``);
+* the pool is viewed as ``[(n,) num_blocks, bs, K*dh]`` (a free reshape of
+  the trailing ``[K, dh]``), so one table entry DMAs one dense
+  ``(bs, K*dh)`` tile and KV head ``h`` is the lane slice
+  ``[h*dh, (h+1)*dh)``.
+
+The score and value matmuls then run per KV head as plain 2-D
+``[Q*G, dh] x [bs, dh]^T`` and ``[Q*G, bs] x [bs, dv]`` products.
+
+Online softmax state (running max / denominator / unnormalized f32
+accumulator) lives in VMEM scratch that persists across the sequential table
+sweep; the last step normalizes into the output block, whose index map
+ignores ``j``.
 
 Causal masking inside the query block: query ``i`` (0-based of Q) sits at
 absolute position ``kv_len - Q + i`` and attends keys
@@ -42,11 +56,6 @@ streaming dead pool blocks.  Per-slot HBM traffic is therefore O(kv_len)
 (O(window + Q) for windowed families), not O(max_len); the caller is still
 free to slice ``tables`` down to the live-block high-water mark so the grid
 itself shrinks too.
-
-(The pool keeps the model's trailing ``[K, dh]`` feature layout, so a K/V
-block tile is ``(bs, K, dh)`` with the small kv-head dim second-to-last —
-suboptimal TPU sublane tiling for tiny K, traded for gather/scatter-free
-interop with the serving cache pytree.)
 """
 
 from __future__ import annotations
@@ -63,8 +72,9 @@ NEG = -1e30
 
 def _paged_kernel(
     tbl_ref, len_ref, lay_ref,     # scalar-prefetch: tables [S,M], kv_len [S],
-    q_ref, k_ref, v_ref,           #   layer [1]; then q [1, Q*H, dh] and the
-    o_ref, m_ref, l_ref,           #   K/V blocks [1, 1, bs, K, d*]; outputs
+    q_ref, k_ref, v_ref,           #   layer [1]; then q [1, K, Q*G, dh] and
+    o_ref,                         #   the K/V tiles [1, 1, bs, K*d*]; output
+    m_sc, l_sc, acc_sc,            # scratch: [K, Q*G, 1] x2, [K, Q*G, dv]
     *, scale: float, window: int | None, block_size: int,
     n_kv: int, q_per_kv: int, q_len: int,
 ):
@@ -74,12 +84,14 @@ def _paged_kernel(
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        o_ref[...] = jnp.zeros_like(o_ref)
+        m_sc[...] = jnp.full_like(m_sc, NEG)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
 
     kvl = len_ref[s]
     K, G, Q = n_kv, q_per_kv, q_len
+    dh = q_ref.shape[-1]
+    dv = acc_sc.shape[-1]
 
     # early exit: skip table entries past the last live position, and — for
     # windowed attention — entries wholly before the oldest query's reach
@@ -89,48 +101,43 @@ def _paged_kernel(
 
     @pl.when(live)
     def _accumulate():
-        q = q_ref[0].astype(jnp.float32).reshape(Q, K, G, -1)
-        kb = k_ref[0, 0].astype(jnp.float32)                 # [bs, K, dh]
-        vb = v_ref[0, 0].astype(jnp.float32)                 # [bs, K, dv]
-        sc = jnp.einsum(
-            "qkgd,bkd->qkgb", q, kb, preferred_element_type=jnp.float32
-        ) * scale                                            # [Q, K, G, bs]
-
         pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, 1, block_size), 3
+            jnp.int32, (Q * G, block_size), 1
         )
-        # per-query causal limit: query i attends keys < kvl - (Q - 1 - i)
-        limit = kvl - (Q - 1) + jax.lax.broadcasted_iota(
-            jnp.int32, (Q, 1, 1, 1), 0
-        )
+        # per-query causal limit: row r is query r // G, which attends keys
+        # < kvl - (Q - 1 - r // G)
+        qi = jax.lax.broadcasted_iota(jnp.int32, (Q * G, block_size), 0) // G
+        limit = kvl - (Q - 1) + qi
         mask = pos < limit
         if window is not None:
             mask &= pos > limit - 1 - window
-        sc = jnp.where(mask, sc, NEG)
-
-        m_prev = m_ref[0].reshape(Q, K, G)
-        l_prev = l_ref[0].reshape(Q, K, G)
-        m_new = jnp.maximum(m_prev, sc.max(-1))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(sc - m_new[..., None])
-        p = jnp.where(mask, p, 0.0)
-        l_new = l_prev * corr + p.sum(-1)
-        acc = o_ref[0].astype(jnp.float32).reshape(Q, K, G, -1) * corr[..., None]
-        acc = acc + jnp.einsum(
-            "qkgb,bkv->qkgv", p, vb, preferred_element_type=jnp.float32
-        )
-        m_ref[0] = m_new.reshape(Q * K * G)
-        l_ref[0] = l_new.reshape(Q * K * G)
-        # o_ref is f32: re-quantizing the running accumulator through the
-        # model dtype every block step would compound bf16 rounding over
-        # long kv_lens and drift off the gathered-dense oracle
-        o_ref[0] = acc.reshape(Q * K * G, -1)
+        kb = k_ref[0, 0].astype(jnp.float32)                 # [bs, K*dh]
+        vb = v_ref[0, 0].astype(jnp.float32)                 # [bs, K*dv]
+        for h in range(K):
+            q = q_ref[0, h].astype(jnp.float32)              # [Q*G, dh]
+            sc = jax.lax.dot_general(
+                q, kb[:, h * dh:(h + 1) * dh], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                        # [Q*G, bs]
+            sc = jnp.where(mask, sc, NEG)
+            m_prev = m_sc[h]                                 # [Q*G, 1]
+            m_new = jnp.maximum(m_prev, sc.max(-1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
+            l_sc[h] = l_sc[h] * corr + p.sum(-1, keepdims=True)
+            m_sc[h] = m_new
+            # the accumulator stays f32: re-quantizing it through the model
+            # dtype every block step would compound bf16 rounding over long
+            # kv_lens and drift off the gathered-dense oracle
+            acc_sc[h] = acc_sc[h] * corr + jnp.dot(
+                p, vb[:, h * dv:(h + 1) * dv],
+                preferred_element_type=jnp.float32,
+            )
 
     @pl.when(j == nj - 1)
     def _normalize():
-        l = l_ref[0]
-        denom = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = o_ref[0] / denom[:, None]
+        l = l_sc[...]
+        o_ref[0] = acc_sc[...] / jnp.where(l == 0.0, 1.0, l)
 
 
 @functools.partial(
@@ -155,16 +162,19 @@ def paged_attention_pallas(
     if k_pool.ndim == 4:  # single-layer pool: lift to the stacked layout
         k_pool, v_pool = k_pool[None], v_pool[None]
         layer = jnp.zeros((), jnp.int32)
-    _, _, bs, K, dv = v_pool.shape
+    n, nb, bs, K, dv = v_pool.shape
     M = tables.shape[1]
     G = H // K
     assert K * G == H, (H, K)
     tables = tables.astype(jnp.int32)
     kv_len = kv_len.astype(jnp.int32)
     lay = jnp.asarray(layer, jnp.int32).reshape(1)
-    # the Q query rows ride the row axis of one block: every fetched K/V
-    # block is scored against all of them at once
-    qf = q.reshape(S, Q * H, dh)
+    # KV head leading; its G query heads x Q query tokens on the row axis:
+    # every fetched K/V block is scored against all Q*G rows at once
+    qk = q.reshape(S, Q, K, G, dh).transpose(0, 2, 1, 3, 4)
+    qk = qk.reshape(S, K, Q * G, dh)
+    kp = k_pool.reshape(n, nb, bs, K * dh)
+    vp = v_pool.reshape(n, nb, bs, K * dv)
 
     def kv_map(s, j, tbl, kvl, lay):
         # clamp dead entries onto the live range [first, last]: same index as
@@ -176,20 +186,24 @@ def paged_attention_pallas(
         if window is not None:
             first = jnp.maximum(kvl[s] - (Q - 1) - window, 0) // bs
             jj = jnp.maximum(jj, jnp.minimum(first, last))
-        return (lay[0], tbl[s, jj], 0, 0, 0)
+        return (lay[0], tbl[s, jj], 0, 0)
+
+    def slot_map(s, j, tbl, kvl, lay):
+        return (s, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S, M),
         in_specs=[
-            pl.BlockSpec((1, Q * H, dh), lambda s, j, tbl, kvl, lay: (s, 0, 0)),
-            pl.BlockSpec((1, 1, bs, K, dh), kv_map),
-            pl.BlockSpec((1, 1, bs, K, dv), kv_map),
+            pl.BlockSpec((1, K, Q * G, dh), slot_map),
+            pl.BlockSpec((1, 1, bs, K * dh), kv_map),
+            pl.BlockSpec((1, 1, bs, K * dv), kv_map),
         ],
-        out_specs=[
-            pl.BlockSpec((1, Q * H, dv), lambda s, j, tbl, kvl, lay: (s, 0, 0)),
-            pl.BlockSpec((1, Q * H), lambda s, j, tbl, kvl, lay: (s, 0)),
-            pl.BlockSpec((1, Q * H), lambda s, j, tbl, kvl, lay: (s, 0)),
+        out_specs=pl.BlockSpec((1, K, Q * G, dv), slot_map),
+        scratch_shapes=[
+            pltpu.VMEM((K, Q * G, 1), jnp.float32),
+            pltpu.VMEM((K, Q * G, 1), jnp.float32),
+            pltpu.VMEM((K, Q * G, dv), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -198,12 +212,9 @@ def paged_attention_pallas(
             n_kv=K, q_per_kv=G, q_len=Q,
         ),
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((S, Q * H, dv), jnp.float32),
-            jax.ShapeDtypeStruct((S, Q * H), jnp.float32),
-            jax.ShapeDtypeStruct((S, Q * H), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((S, K, Q * G, dv), jnp.float32),
         interpret=interpret,
-    )(tables, kv_len, lay, qf, k_pool, v_pool)
-    o = out[0].reshape(S, Q, H, dv).astype(q.dtype)
+    )(tables, kv_len, lay, qk, kp, vp)
+    o = out.reshape(S, K, Q, G, dv).transpose(0, 2, 1, 3, 4)
+    o = o.reshape(S, Q, H, dv).astype(q.dtype)
     return o[:, 0] if squeeze else o

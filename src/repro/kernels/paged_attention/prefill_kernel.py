@@ -40,10 +40,18 @@ zero out anyway.  Per-(slot, q-block) HBM traffic is O(causal reach), i.e.
 full prefill costs ~half the dense quadratic sweep and chunked prefill costs
 O(kv_len) not O(bucket ceiling).
 
-Online-softmax state (running max / denominator / unnormalized accumulator)
-lives in revisited output blocks indexed (slot, q-block) whose maps ignore
-the table step — VMEM-resident across the sweep, normalized in place on the
-last step.
+TPU layout, as in the decode kernel: q rides KV-head-leading as
+``[S, K, Q*G, dh]`` (row ``r`` of a head group is query ``r // G``) and the
+pool is viewed as ``[(n,) num_blocks, bs, K*dh]``, so every matmul is a 2-D
+product per KV head.  Rope's rotate-half is a matmul with a signed
+permutation (exact: each output lane picks one input lane), which keeps the
+prologue free of half-width lane slices; the rope frequencies come in from
+the wrapper (``rope_freqs``, duplicated over both halves) so the angles are
+the model's own.
+
+Online-softmax state (running max / denominator / unnormalized f32
+accumulator) lives in VMEM scratch that persists across the table sweep of
+one (slot, q-block); the last step normalizes into the output block.
 """
 
 from __future__ import annotations
@@ -60,12 +68,13 @@ NEG = -1e30
 
 def _prefill_kernel(
     tbl_ref, len_ref, lay_ref,     # scalar-prefetch: tables [S,M], kv_len [S],
-    q_ref, qs_ref, k_ref, v_ref,   #   layer [1]; q tile [1, QB*H, dh], q_norm
-    o_ref, m_ref, l_ref,           #   scale [1, dh], K/V blocks [1,1,bs,K,d*]
-    q_vmem,                        # scratch: prepared f32 q tile [QB*H, dh]
+    q_ref, qs_ref, fr_ref, rot_ref,  # layer [1]; q tile [1, K, QB*G, dh],
+    k_ref, v_ref,                  #   q_norm scale [1, dh], rope freqs [1, dh],
+    o_ref,                         #   rotate-half [dh, dh]; K/V [1,1,bs,K*d*]
+    q_sc, m_sc, l_sc, acc_sc,      # scratch: prepared f32 q tile, softmax state
     *, scale: float, window: int | None, block_size: int,
     n_kv: int, q_per_kv: int, q_len: int, q_blk: int,
-    has_qnorm: bool, eps: float, rope_theta: float,
+    has_qnorm: bool, eps: float,
 ):
     s = pl.program_id(0)
     iq = pl.program_id(1)
@@ -74,38 +83,37 @@ def _prefill_kernel(
 
     kvl = len_ref[s]
     K, G, Q, QB = n_kv, q_per_kv, q_len, q_blk
+    R = QB * G
     qlo = iq * QB
     dh = q_ref.shape[-1]
-    half = dh // 2
+    dv = acc_sc.shape[-1]
+    # query index (within the whole Q) of every row of a head group
+    qi = qlo + jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // G
 
     @pl.when(j == 0)
     def _prologue():
-        m_ref[...] = jnp.full_like(m_ref, NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        o_ref[...] = jnp.zeros_like(o_ref)
+        m_sc[...] = jnp.full_like(m_sc, NEG)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
         # fused entry: rmsnorm (optional) + rope on the raw query tile, once
         # per (slot, q-block); requantize through the model dtype after each
-        # stage so the result bit-matches the jnp rms_head_norm/apply_rope
-        # chain (each returns x.dtype) feeding the generic attention path
-        x = q_ref[0].astype(jnp.float32)                     # [QB*H, dh]
-        if has_qnorm:
-            var = (x * x).mean(-1, keepdims=True)
-            x = x * jax.lax.rsqrt(var + eps) * qs_ref[0].astype(jnp.float32)
-            x = x.astype(q_ref.dtype).astype(jnp.float32)
-        # rope angles from in-kernel positions: query i at kvl - Q + qlo + i
-        xq = x.reshape(QB, K * G, dh)
-        io2 = jax.lax.broadcasted_iota(jnp.float32, (1, half), 1)
-        freqs = 1.0 / (rope_theta ** ((2.0 * io2) / dh))     # rope_freqs
-        pos_q = (kvl - Q + qlo) + jax.lax.broadcasted_iota(
-            jnp.int32, (QB, 1), 0
-        )
-        ang = pos_q.astype(jnp.float32) * freqs              # [QB, dh/2]
-        cos = jnp.cos(ang)[:, None, :]
-        sin = jnp.sin(ang)[:, None, :]
-        x1, x2 = xq[..., :half], xq[..., half:]
-        xr = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-        xr = xr.astype(q_ref.dtype).astype(jnp.float32)
-        q_vmem[...] = xr.reshape(QB * K * G, dh)
+        # stage so the result matches the jnp rms_head_norm/apply_rope chain
+        # (each returns x.dtype) feeding the generic attention path
+        ang = (kvl - Q + qi).astype(jnp.float32) * fr_ref[...]   # [R, dh]
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        rot = rot_ref[...]
+        for h in range(K):
+            x = q_ref[0, h].astype(jnp.float32)                  # [R, dh]
+            if has_qnorm:
+                var = (x * x).mean(-1, keepdims=True)
+                x = x * jax.lax.rsqrt(var + eps) * qs_ref[...].astype(
+                    jnp.float32)
+                x = x.astype(q_ref.dtype).astype(jnp.float32)
+            xr = x * cos + jnp.dot(
+                x, rot, precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32,
+            ) * sin
+            q_sc[h] = xr.astype(q_ref.dtype).astype(jnp.float32)
 
     # early exit: skip entries past this q-block's causal reach (upper
     # triangle) or the slot's live range; windowed families also skip entries
@@ -117,46 +125,37 @@ def _prefill_kernel(
 
     @pl.when(live)
     def _accumulate():
-        q = q_vmem[...].reshape(QB, K, G, -1)
-        kb = k_ref[0, 0].astype(jnp.float32)                 # [bs, K, dh]
-        vb = v_ref[0, 0].astype(jnp.float32)                 # [bs, K, dv]
-        sc = jnp.einsum(
-            "qkgd,bkd->qkgb", q, kb, preferred_element_type=jnp.float32
-        ) * scale                                            # [QB, K, G, bs]
-
         pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, 1, block_size), 3
+            jnp.int32, (R, block_size), 1
         )
-        # per-query causal limit: query qlo+i attends keys
-        # < kvl - (Q - 1 - (qlo + i))
-        limit = kvl - (Q - 1) + qlo + jax.lax.broadcasted_iota(
-            jnp.int32, (QB, 1, 1, 1), 0
-        )
+        # per-query causal limit: query qi attends keys < kvl - (Q - 1 - qi)
+        limit = kvl - (Q - 1) + qi
         mask = pos < limit
         if window is not None:
             mask &= pos > limit - 1 - window
-        sc = jnp.where(mask, sc, NEG)
-
-        m_prev = m_ref[0].reshape(QB, K, G)
-        l_prev = l_ref[0].reshape(QB, K, G)
-        m_new = jnp.maximum(m_prev, sc.max(-1))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(sc - m_new[..., None])
-        p = jnp.where(mask, p, 0.0)
-        l_new = l_prev * corr + p.sum(-1)
-        acc = o_ref[0].astype(jnp.float32).reshape(QB, K, G, -1)
-        acc = acc * corr[..., None] + jnp.einsum(
-            "qkgb,bkv->qkgv", p, vb, preferred_element_type=jnp.float32
-        )
-        m_ref[0] = m_new.reshape(QB * K * G)
-        l_ref[0] = l_new.reshape(QB * K * G)
-        o_ref[0] = acc.reshape(QB * K * G, -1)
+        kb = k_ref[0, 0].astype(jnp.float32)                 # [bs, K*dh]
+        vb = v_ref[0, 0].astype(jnp.float32)                 # [bs, K*dv]
+        for h in range(K):
+            sc = jax.lax.dot_general(
+                q_sc[h], kb[:, h * dh:(h + 1) * dh], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                        # [R, bs]
+            sc = jnp.where(mask, sc, NEG)
+            m_prev = m_sc[h]                                 # [R, 1]
+            m_new = jnp.maximum(m_prev, sc.max(-1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
+            l_sc[h] = l_sc[h] * corr + p.sum(-1, keepdims=True)
+            m_sc[h] = m_new
+            acc_sc[h] = acc_sc[h] * corr + jnp.dot(
+                p, vb[:, h * dv:(h + 1) * dv],
+                preferred_element_type=jnp.float32,
+            )
 
     @pl.when(j == nj - 1)
     def _normalize():
-        l = l_ref[0]
-        denom = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = o_ref[0] / denom[:, None]
+        l = l_sc[...]
+        o_ref[0] = acc_sc[...] / jnp.where(l == 0.0, 1.0, l)
 
 
 def pick_q_block(q_len: int, q_block: int) -> int:
@@ -189,23 +188,37 @@ def paged_prefill_pallas(
     rope_theta: float = 10000.0,
     q_block: int = 32,
 ) -> jax.Array:
+    from repro.models.layers import rope_freqs
+
     S, Q, H, dh = q.shape
     if k_pool.ndim == 4:  # single-layer pool: lift to the stacked layout
         k_pool, v_pool = k_pool[None], v_pool[None]
         layer = jnp.zeros((), jnp.int32)
-    _, _, bs, K, dv = v_pool.shape
+    n, nb, bs, K, dv = v_pool.shape
     M = tables.shape[1]
     G = H // K
     assert K * G == H, (H, K)
     QB = pick_q_block(Q, q_block)
     nq = Q // QB
+    half = dh // 2
     tables = tables.astype(jnp.int32)
     kv_len = kv_len.astype(jnp.int32)
     lay = jnp.asarray(layer, jnp.int32).reshape(1)
     has_qnorm = q_norm is not None
     qs = (q_norm if has_qnorm else jnp.ones((dh,), q.dtype)).reshape(1, dh)
-    # query rows ride the row axis: q-block iq owns rows [iq*QB*H, (iq+1)*QB*H)
-    qf = q.reshape(S, Q * H, dh)
+    fr = jnp.tile(rope_freqs(dh, rope_theta), 2).reshape(1, dh)
+    # x @ rot == concat(-x2, x1): rotate-half as one exact matmul
+    i = jnp.arange(dh)
+    rot = (
+        jnp.where(i[:, None] == i[None, :] + half, -1.0, 0.0)
+        + jnp.where(i[:, None] + half == i[None, :], 1.0, 0.0)
+    ).astype(jnp.float32)
+    # KV head leading; q-block iq owns rows [iq*QB*G, (iq+1)*QB*G) of each
+    # head group
+    qk = q.reshape(S, Q, K, G, dh).transpose(0, 2, 1, 3, 4)
+    qk = qk.reshape(S, K, Q * G, dh)
+    kp = k_pool.reshape(n, nb, bs, K * dh)
+    vp = v_pool.reshape(n, nb, bs, K * dv)
 
     def kv_map(s, iq, j, tbl, kvl, lay):
         # clamp dead entries onto the live causal band [first, lastq]: same
@@ -220,39 +233,43 @@ def paged_prefill_pallas(
         if window is not None:
             first = jnp.maximum(kvl[s] - (Q - 1) + iq * QB - window, 0) // bs
             jj = jnp.maximum(jj, jnp.minimum(first, lastq))
-        return (lay[0], tbl[s, jj], 0, 0, 0)
+        return (lay[0], tbl[s, jj], 0, 0)
 
     def q_map(s, iq, j, tbl, kvl, lay):
-        return (s, iq, 0)
+        return (s, 0, iq, 0)
 
+    def const_map(s, iq, j, tbl, kvl, lay):
+        return (0, 0)
+
+    R = QB * G
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S, nq, M),
         in_specs=[
-            pl.BlockSpec((1, QB * H, dh), q_map),
-            pl.BlockSpec((1, dh), lambda s, iq, j, tbl, kvl, lay: (0, 0)),
-            pl.BlockSpec((1, 1, bs, K, dh), kv_map),
-            pl.BlockSpec((1, 1, bs, K, dv), kv_map),
+            pl.BlockSpec((1, K, R, dh), q_map),
+            pl.BlockSpec((1, dh), const_map),
+            pl.BlockSpec((1, dh), const_map),
+            pl.BlockSpec((dh, dh), const_map),
+            pl.BlockSpec((1, 1, bs, K * dh), kv_map),
+            pl.BlockSpec((1, 1, bs, K * dv), kv_map),
         ],
-        out_specs=[
-            pl.BlockSpec((1, QB * H, dv), q_map),
-            pl.BlockSpec((1, QB * H), lambda s, iq, j, tbl, kvl, lay: (s, iq)),
-            pl.BlockSpec((1, QB * H), lambda s, iq, j, tbl, kvl, lay: (s, iq)),
+        out_specs=pl.BlockSpec((1, K, R, dv), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((K, R, dh), jnp.float32),
+            pltpu.VMEM((K, R, 1), jnp.float32),
+            pltpu.VMEM((K, R, 1), jnp.float32),
+            pltpu.VMEM((K, R, dv), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((QB * H, dh), jnp.float32)],
     )
     out = pl.pallas_call(
         functools.partial(
             _prefill_kernel, scale=scale, window=window, block_size=bs,
             n_kv=K, q_per_kv=G, q_len=Q, q_blk=QB, has_qnorm=has_qnorm,
-            eps=eps, rope_theta=rope_theta,
+            eps=eps,
         ),
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((S, Q * H, dv), jnp.float32),
-            jax.ShapeDtypeStruct((S, Q * H), jnp.float32),
-            jax.ShapeDtypeStruct((S, Q * H), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((S, K, Q * G, dv), jnp.float32),
         interpret=interpret,
-    )(tables, kv_len, lay, qf, qs, k_pool, v_pool)
-    return out[0].reshape(S, Q, H, dv).astype(q.dtype)
+    )(tables, kv_len, lay, qk, qs, fr, rot, kp, vp)
+    o = out.reshape(S, K, Q, G, dv).transpose(0, 2, 1, 3, 4)
+    return o.reshape(S, Q, H, dv).astype(q.dtype)

@@ -66,16 +66,10 @@ def _compile_cell(cfg, shape, mesh, profile, grad_accum):
     return cell, compiled, _read_new_spmd_dump(snap)
 
 
-def _cost_analysis(compiled) -> dict:
-    # older jaxlibs return [per-device dict], newer a flat dict
-    cost = compiled.cost_analysis()
-    return cost[0] if isinstance(cost, (list, tuple)) else cost
-
-
 def _cost_vector(compiled, spmd_hlo: str | None = None) -> dict:
     from repro.launch.hlo_analysis import collective_stats
 
-    cost = _cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     colls = collective_stats(spmd_hlo if spmd_hlo else compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),
@@ -144,7 +138,7 @@ def run_cell(
         t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    cost = _cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     colls = collective_stats(_read_new_spmd_dump(snap) or hlo)
 

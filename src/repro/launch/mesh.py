@@ -8,32 +8,34 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, *, devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    The logical-axis rules (``parallel.sharding``) place activations through
+    ``with_sharding_constraint``, which accepts only ``Auto`` axes; since
+    jax 0.7 ``make_mesh`` defaults to ``Explicit`` ones.
+    """
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 4, model: int = 2) -> jax.sharding.Mesh:
-    """Small mesh over whatever local devices exist (CPU tests).
-
-    Defaults to data=4/model=2 (not 2x4): this jaxlib's CPU backend
-    reproducibly segfaults compiling SPMD programs on a 2x4 data/model
-    mesh, while the transposed shape compiles fine.
-    """
+    """Small (data, model) mesh over whatever local devices exist."""
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // data))
-    if (data, model) == (2, 4):
-        # fail loudly instead of letting jaxlib take the whole process down
-        raise ValueError(
-            "host mesh shape data=2 x model=4 is known to segfault this "
-            "jaxlib's CPU backend while compiling SPMD programs; use the "
-            "transposed make_host_mesh(data=4, model=2) (the default) instead"
-        )
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 def make_pipeline_mesh(pp: int, dp: int = 1, tp: int = 1) -> jax.sharding.Mesh:

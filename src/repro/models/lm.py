@@ -305,7 +305,12 @@ def forward(
             new_layer_cache = {} if layer_cache is not None else None
             captured = {}
             for j, kind in enumerate(kinds):
-                col = LayerScoped(collector, offset + g * len(kinds) + j)
+                # the inert collector stays itself: blocks test for it to
+                # take fused paths (flash prefill) that tag nothing
+                col = (
+                    collector if collector is NULL_COLLECTOR
+                    else LayerScoped(collector, offset + g * len(kinds) + j)
+                )
                 blk_cache = None if layer_cache is None else layer_cache[f"b{j}"]
                 blk_paged = None
                 if pool_c is not None:

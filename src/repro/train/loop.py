@@ -42,7 +42,11 @@ from repro.core.tracing.tracer import Tracer
 from repro.data.pipeline import DataConfig, SyntheticTokens
 from repro.models.hooks import NULL_COLLECTOR
 from repro.train.optim import OptimizerConfig
-from repro.train.train_step import init_train_state, make_train_step
+from repro.train.train_step import (
+    init_train_state,
+    make_train_step,
+    train_state_axes,
+)
 
 log = logging.getLogger("repro.train")
 
@@ -96,21 +100,42 @@ def _aot_train_step(jit_fn, avatars, *, cache, key_parts, registry):
 
 
 def _step_flops(jit_step, state, batch) -> float:
-    """Model flops of one jitted step via XLA's cost analysis (the MFU
-    numerator).  ``Lowered.cost_analysis`` needs no compile; fall back to
-    the compiled executable's analysis, and to 0.0 (series disabled) on
-    backends exposing neither."""
+    """FLOPs XLA's cost analysis attributes to one jitted step (the
+    model-flops/s numerator; recomputation under remat counts too, and a
+    scanned layer body counts once).  ``Lowered.cost_analysis`` needs no
+    compile where the backend offers it; the TPU offers it only on the
+    compiled program (whose compile the persistent cache then serves to the
+    first step).  A failure or a zero count is logged and returns 0.0,
+    which leaves the flops/s series empty."""
     try:
         lowered = jit_step.lower(state, batch)
-        try:
-            cost = lowered.cost_analysis()
-        except Exception:
+        cost = lowered.cost_analysis()
+        if cost is None:
             cost = lowered.compile().cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
-        return float(cost.get("flops", 0.0) or 0.0)
+        flops = float(cost["flops"])
     except Exception:
+        log.exception("train step cost analysis failed; no flops/s series")
         return 0.0
+    if flops == 0.0:
+        log.warning("train step cost analysis counted no flops; no flops/s "
+                    "series")
+    return flops
+
+
+def _place_state(cfg: ModelConfig, state):
+    """Lay a fresh state out by the logical-axis rules of the installed mesh
+    (ZeRO over ``data``, TP over ``model``), as every step after the first
+    leaves it.  Left unplaced, the first step reads one replicated copy per
+    device (a full-width pipelined step then overflows a chip), and the
+    second step compiles again for the placed state its first one returned."""
+    from repro.parallel.sharding import current_mesh_and_rules, param_shardings
+
+    mesh, rules = current_mesh_and_rules()
+    if mesh is None:
+        return state
+    return jax.device_put(
+        state, param_shardings(train_state_axes(cfg), state, mesh, rules)
+    )
 
 
 def _shardings(state):
@@ -202,7 +227,9 @@ def train(
     ds = SyntheticTokens(data_cfg)
     if state is None:
         with tracer.scope("init", op="init"):
-            state = init_train_state(cfg, jax.random.PRNGKey(loop.seed))
+            state = _place_state(
+                cfg, init_train_state(cfg, jax.random.PRNGKey(loop.seed))
+            )
     if controller is not None:
         controller.registry = registry
 
@@ -297,10 +324,10 @@ def train(
     # MFU numerator, once: the flops XLA attributes to one step (lowering
     # uses the same in-memory jit, so the first real call still compiles
     # exactly once).  Only probed when someone will read the series.
-    flops = (
-        _step_flops(jit_step, state, ds.batch_at(start))
-        if registry is not None else 0.0
-    )
+    flops = 0.0
+    if registry is not None:
+        flops = _step_flops(jit_step, state, ds.batch_at(start))
+        registry.gauge("train.step_flops").set(flops)
     tokens_per_step = data_cfg.global_batch * data_cfg.seq_len
 
     guards_on = controller is not None and (

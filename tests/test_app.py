@@ -167,6 +167,8 @@ class TestCLISmoke:
         env["PYTHONPATH"] = str(ROOT / "src") + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
         env["REPRO_DRYRUN_DEVICES"] = "8"
+        # keep JAX's persistent cache out of the checkout
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
         proc = subprocess.run(
             [sys.executable, "-m", "repro", "dryrun", "--arch", ARCH,
              "--shape", "train_4k", "--smoke", "--host-mesh",
@@ -341,6 +343,13 @@ class TestSessionPlumbing:
 
 
 class TestShims:
+    @pytest.fixture(autouse=True)
+    def _jax_cache_env(self, monkeypatch, tmp_path):
+        # the shims enter through cli.main, which turns on JAX's persistent
+        # cache unless this is set: keep it out of the checkout and of the
+        # process-wide config later tests compile under
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+
     def test_launch_train_shim(self, capsys):
         from repro.launch.train import main as legacy_train
 

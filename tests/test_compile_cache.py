@@ -160,13 +160,16 @@ print("HIT" if hit else "MISS", [float(x) for x in out])
 """
 
 
-def test_cross_process_reuse(tmp_path):
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_cross_process_reuse(tmp_path, n_dev):
     """The actual restart scenario: process 2 must hit entries process 1
-    wrote, and the deserialized executable must compute the same thing."""
+    wrote, and the deserialized executable must compute the same thing —
+    also in a process with more local devices than the executable uses."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         filter(None, [os.path.abspath("src"), env.get("PYTHONPATH", "")])
     )
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
 
     def run():
         r = subprocess.run(
@@ -179,3 +182,33 @@ def test_cross_process_reuse(tmp_path):
     first, second = run(), run()
     assert first.startswith("MISS") and second.startswith("HIT")
     assert first.split(" ", 1)[1] == second.split(" ", 1)[1]
+
+
+# ------------------------------------------------- JAX persistent cache ---
+
+
+def test_use_jax_cache_leaves_env_dir_alone(monkeypatch, tmp_path):
+    from repro.core.compile_cache import use_jax_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert use_jax_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_use_jax_cache_defaults_to_fixed_checkout_path(monkeypatch):
+    from pathlib import Path
+
+    from repro.core.compile_cache import use_jax_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    root = Path(__file__).resolve().parents[1]
+    try:
+        first, second = use_jax_cache(), use_jax_cache()
+        assert first == second == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    ignored = (root / ".gitignore").read_text().splitlines()
+    assert ".jax_cache/" in ignored

@@ -18,6 +18,7 @@ from repro.core.dpp.planner import Planner
 from repro.core.dpp.schedule import legalize, sched_bfc, sched_dfc, sched_wave
 from repro.core.simkit.engine import DeadlockError, Engine, FaultModel
 from repro.core.simkit.workload import ModelProfile, Topology, build_training_step
+from repro.launch.mesh import auto_mesh
 
 
 # ------------------------------------------------------------- schedules ---
@@ -125,7 +126,7 @@ def test_engine_detects_deadlock_on_mismatched_collective_order():
 
 
 def _mesh_stage(n=4):
-    return jax.make_mesh((n,), ("stage",))
+    return auto_mesh((n,), ("stage",))
 
 
 def _block(p, x):

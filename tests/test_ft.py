@@ -26,6 +26,7 @@ from repro.ft import (
     parse_link,
     simulate_policy,
 )
+from repro.launch.mesh import auto_mesh
 from repro.obs.detector import DetectionUpdate
 
 TINY = ["--arch", "qwen2-0.5b", "--smoke",
@@ -224,9 +225,9 @@ class TestCheckpointFailureModes:
         want = bits(st)
         # restore onto a replicated 1-device mesh and, when the host mesh
         # has more devices, onto a data-sharded one: same bits both ways
-        meshes = [(jax.make_mesh((1,), ("data",)), P())]
+        meshes = [(auto_mesh((1,), ("data",)), P())]
         if len(jax.devices()) >= 2:
-            meshes.append((jax.make_mesh((2,), ("data",)), P("data")))
+            meshes.append((auto_mesh((2,), ("data",)), P("data")))
         for mesh, pspec in meshes:
             sh = jax.tree.map(lambda _: NamedSharding(mesh, pspec), st)
             restored, _ = restore(tmp_path, st, shardings=sh)

@@ -28,7 +28,7 @@ from repro.configs import get_config
 from repro.configs.base import ModelConfig
 from repro.core.dpp.executor import build_time_table
 from repro.data.pipeline import DataConfig, SyntheticTokens
-from repro.launch.mesh import make_pipeline_mesh
+from repro.launch.mesh import auto_mesh, make_pipeline_mesh
 from repro.models import lm
 from repro.models import pipeline as pl
 from repro.parallel.plan import ParallelPlan, forward_order, resolve_plan
@@ -100,7 +100,7 @@ def test_matrix_cell_loss_parity(dp, tp, pp, ga, sched):
             got = _run(jax.jit(make_train_step(TINY, OCFG, grad_accum=ga)))
         else:
             # sharded DP/TP path: fused step under a (data, model) mesh
-            mesh = jax.make_mesh((dp, tp), ("data", "model"))
+            mesh = auto_mesh((dp, tp), ("data", "model"))
             with mesh, axis_rules(mesh, DEFAULT_RULES):
                 got = _run(jax.jit(make_train_step(TINY, OCFG, grad_accum=ga)))
     else:

@@ -337,14 +337,24 @@ def test_emit_pipeline_events_matches_table_occupancy():
 # ------------------------------------------------------- mesh satellite -----
 
 
-def test_host_mesh_guard_rejects_segfaulting_shape():
-    if len(jax.devices()) < 8:
-        pytest.skip("needs 8 host devices")
-    with pytest.raises(ValueError, match="segfault"):
-        make_host_mesh(data=2, model=4)
-    # the default transposed shape still builds
-    m = make_host_mesh()
-    assert dict(m.shape) == {"data": 4, "model": 2}
+@pytest.mark.parametrize("data,model", [(4, 2), (2, 4)])
+def test_host_mesh_auto_axes_take_sharding_constraints(data, model):
+    """Host meshes carry Auto axes, so the logical-axis rules can constrain
+    activations with ``with_sharding_constraint`` in either orientation."""
+    from jax.sharding import AxisType
+
+    from repro.parallel.sharding import DEFAULT_RULES, axis_rules, shard_act
+
+    if len(jax.devices()) < data * model:
+        pytest.skip(f"needs {data * model} host devices")
+    m = make_host_mesh(data=data, model=model)
+    assert dict(m.shape) == {"data": data, "model": model}
+    assert set(m.axis_types) == {AxisType.Auto}
+    x = jnp.arange(8 * 16, dtype=jnp.float32).reshape(8, 16)
+    with m, axis_rules(m, DEFAULT_RULES):
+        y = jax.jit(lambda a: shard_act(a * 2, ("batch", "mlp")))(x)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(x) * 2)
+    assert y.sharding.spec == jax.sharding.PartitionSpec("data", "model")
 
 
 def test_pipeline_mesh_too_few_devices():

@@ -80,10 +80,7 @@ def test_logical_spec_axes_never_collide_or_overdivide(data):
     from jax.sharding import AbstractMesh
 
     # abstract mesh: shape-only, no physical devices required
-    try:
-        mesh = AbstractMesh((2, 2, 2), ("pod", "data", "model"))
-    except TypeError:  # jax 0.4.x signature: AbstractMesh(((name, size), ...))
-        mesh = AbstractMesh((("pod", 2), ("data", 2), ("model", 2)))
+    mesh = AbstractMesh((2, 2, 2), ("pod", "data", "model"))
     names = list(DEFAULT_RULES)
     k = data.draw(st.integers(1, 4))
     axes = tuple(data.draw(st.sampled_from(names)) for _ in range(k))

@@ -13,6 +13,7 @@ from repro.data.pipeline import DataConfig, SyntheticTokens, make_pipeline
 from repro.ft.compress import GradCompressor
 from repro.ft.failover import TrainSupervisor
 from repro.ft.mitigation import MitigationAction, MitigationPolicy
+from repro.launch.mesh import auto_mesh
 
 
 # ------------------------------------------------------------------ data ---
@@ -83,7 +84,7 @@ def test_checkpointer_async_and_prune(tmp_path):
 def test_elastic_restore_with_new_sharding(tmp_path):
     st = _toy_state(2.0)
     save(st, 1, tmp_path)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = auto_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), st)
