@@ -211,9 +211,9 @@ class MetricsPlugin(ModulePlugin):
     The instrumented loops (``train.loop``, ``serve.server``) publish their
     standard series into ``session.metrics_registry``; this plugin samples
     the registry every ``obs.every`` steps — appending a flat JSONL row to
-    ``--metrics-out`` and chrome counter events to the session trace (they
-    render as counter tracks next to the spans in the shared
-    ``--trace-out``) — and at finalize writes the ``obs.prom_out``
+    ``--metrics-out`` and, when ``--trace-out`` is set, chrome counter events
+    to the session trace (they render as counter tracks next to the spans in
+    that export, their only reader) — and at finalize writes the ``obs.prom_out``
     Prometheus snapshot and reports the flattened series (plus an MFU
     estimate when ``obs.peak_tflops`` is set).
     """
@@ -236,6 +236,9 @@ class MetricsPlugin(ModulePlugin):
         self._n += 1
         if self._n % max(self._obs.every, 1):
             return
+        counters = bool(self.run_cfg.trace_out) and session.tracer.enabled
+        if self._jsonl is None and not counters:
+            return  # no sink reads a sample
         if not len(self.registry):
             return  # nothing published yet (e.g. an idle serve tick)
         from repro.obs import counter_events, flatten_snapshot
@@ -245,7 +248,7 @@ class MetricsPlugin(ModulePlugin):
         ts = session.tracer.clock()
         if self._jsonl is not None:
             self._jsonl.write({"step": self._n, "ts": ts, **flat})
-        if session.tracer.enabled:
+        if counters:
             session.tracer.events.extend(counter_events(flat, ts=ts))
 
     def finalize(self, session) -> dict:

@@ -27,6 +27,7 @@ from __future__ import annotations
 import json
 import logging
 import time
+from contextlib import nullcontext
 from pathlib import Path
 from typing import Any, Callable
 
@@ -137,8 +138,13 @@ class Session:
         return step_fn
 
     def notify_step(self, events, metrics) -> None:
-        for p in self.plugins:
-            p.on_step(self, events, metrics)
+        # the hooks of a step that traced something get a ``plugins`` host
+        # span; an idle serving tick (no events) adds none
+        span = (self.tracer.scope("plugins", kind="host") if events
+                else nullcontext())
+        with span:
+            for p in self.plugins:
+                p.on_step(self, events, metrics)
 
     def notify_detection(self, update) -> None:
         """Fan one online ``DetectionUpdate`` out to detection listeners

@@ -1,6 +1,7 @@
 """Chrome Tracing Format export/import (loadable in chrome://tracing and
-Perfetto — §3.2, Fig. 1).  Each rank maps to a process; compute and
-communication map to separate threads so overlap is visible."""
+Perfetto — §3.2, Fig. 1).  Each rank maps to a process; compute,
+communication and host work map to separate threads so overlap is
+visible."""
 
 from __future__ import annotations
 
@@ -10,7 +11,7 @@ from typing import Iterable
 
 from repro.core.tracing.events import TraceEvent
 
-_TID = {"compute": 0, "coll": 1, "p2p": 2, "marker": 3}
+_TID = {"compute": 0, "coll": 1, "p2p": 2, "marker": 3, "host": 4}
 
 
 def to_chrome(events: Iterable[TraceEvent]) -> dict:
@@ -35,7 +36,7 @@ def to_chrome(events: Iterable[TraceEvent]) -> dict:
             "name": e.name,
             "ph": "X" if e.dur > 0 else "i",
             "pid": e.rank,
-            "tid": _TID.get(e.kind, 4),
+            "tid": _TID.get(e.kind, len(_TID)),
             "ts": e.ts * 1e6,           # Chrome expects microseconds
             "dur": e.dur * 1e6,
             "cat": e.kind,

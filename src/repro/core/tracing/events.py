@@ -17,7 +17,7 @@ class TraceEvent:
     rank: int
     ts: float          # start, seconds in the *local* (per-rank) clock
     dur: float
-    kind: str = "compute"  # compute | coll | p2p | marker
+    kind: str = "compute"  # compute | coll | p2p | marker | host | counter
     args: dict = field(default_factory=dict)
     # well-known args:
     #   mb: microbatch index        chunk: model-chunk index

@@ -5,6 +5,12 @@ host monotonic clock brackets dispatch of jit-compiled blocks (our CPU test
 runs call ``jax.block_until_ready`` inside the scope for faithful durations).
 Persistence runs on a background thread so tracing never stalls the training
 loop (§3.2 "Log pre-processing").
+
+While the JAX profiler runs, every open scope is also a profiler span named
+``megascan.<name>`` whose stats are the tracer's ``rank`` and the scope's
+scalar args, so MegaScan's spans sit on the device trace's clock beside the
+XLA ops they dispatched.  With no profiler running a scope pays one check
+for it and builds nothing.
 """
 
 from __future__ import annotations
@@ -13,11 +19,15 @@ import json
 import queue
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from pathlib import Path
 from typing import Any, Callable, Iterable
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.tracing.events import TraceEvent
+
+SPAN_PREFIX = "megascan."
 
 
 class Tracer:
@@ -32,6 +42,18 @@ class Tracer:
         self.clock = clock
         self.events: list[TraceEvent] = []
 
+    def annotate(self, name: str, **args: Any):
+        """The profiler span of a scope named ``name``: a ``TraceAnnotation``
+        ``megascan.<name>`` carrying ``rank`` and the scalar ``args`` while
+        the profiler runs, else a no-op context.  ``scope`` opens one; a
+        device call whose ``TraceEvent`` is recorded after the fact (its args
+        known only then) opens one itself."""
+        if not (self.enabled and TraceAnnotation.is_enabled()):
+            return nullcontext()
+        stats = {k: v for k, v in args.items()
+                 if isinstance(v, (int, float, str))}
+        return TraceAnnotation(SPAN_PREFIX + name, rank=self.rank, **stats)
+
     @contextmanager
     def scope(self, name: str, kind: str = "compute", **args: Any):
         if not self.enabled:
@@ -39,7 +61,8 @@ class Tracer:
             return
         t0 = self.clock()
         try:
-            yield self
+            with self.annotate(name, **args):
+                yield self
         finally:
             t1 = self.clock()
             self.events.append(

@@ -270,6 +270,9 @@ def paged_prefill_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, K, Q * G, dv), jnp.float32),
         interpret=interpret,
+        # the kernel's name on the device trace, which the benchmark's
+        # roofline reader matches
+        name="paged_prefill_pallas",
     )(tables, kv_len, lay, qk, qs, fr, rot, kp, vp)
     o = out.reshape(S, K, Q, G, dv).transpose(0, 2, 1, 3, 4)
     return o.reshape(S, Q, H, dv).astype(q.dtype)

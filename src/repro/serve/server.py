@@ -8,7 +8,10 @@ Observability is first-class, mirroring the four-module philosophy:
 
 * every prefill / decode step is bracketed by a MegaScan ``Tracer`` scope, so
   serving timelines flow through the same ``TraceEvent`` pipeline (chrome
-  export, analytics, straggler detection) as training traces;
+  export, analytics, straggler detection) as training traces; the tick's
+  host work (``tick``, ``admit``, ``upload``, ``pull``, ``publish``) has
+  ``host`` scopes of its own, and under the JAX profiler every scope is a
+  ``megascan.<name>`` span beside the device ops (``core.tracing.tracer``);
 * an optional MegaScope ``ScopeCollector`` threads through the model; probe
   captures surface per-slot (the vmapped decode stacks them over the slot
   axis) and are attached to each request's stream records.
@@ -140,6 +143,10 @@ class MegaServe:
         # precompiled bucket skips the jit call cache entirely and an
         # unseen width still traces on demand
         self._aot_exec: dict[tuple, Callable] = {}
+        # set once precompile() has built the ladders: from then on a step
+        # key off them compiles inside a tick (see _note_fallback)
+        self._precompiled = False
+        self._fell_back: set[tuple] = set()
         self.sched = Scheduler(serve_cfg)
         self.tracer = tracer or Tracer(rank=0, enabled=True)
         self.collector = collector
@@ -200,13 +207,13 @@ class MegaServe:
                 paged_flags=self.kv.paged, impl=serve_cfg.paged_attn_impl,
             )
 
-            def decode_fn(params, pool, tables, tokens, pos):
+            def serve_decode(params, pool, tables, tokens, pos):
                 pool, logits, caps = paged_step(params, pool, tables, tokens, pos)
                 return pool, jnp.argmax(logits, -1), caps
         else:
             slot_step = make_slot_decode_step(cfg, collector)
 
-            def decode_fn(params, pool, tables, tokens, pos):
+            def serve_decode(params, pool, tables, tokens, pos):
                 dense = self.kv.gather(pool, tables)
                 new_dense, logits, caps = slot_step(params, dense, tokens, pos)
                 pool = self.kv.scatter_decode(pool, new_dense, tables, pos)
@@ -215,9 +222,13 @@ class MegaServe:
         # donate the pool: it is the largest buffer in the program and every
         # step rewrites it, so double-buffering it would waste a full KV pool.
         # The unwrapped jit is kept separate so precompile() can .lower() it
-        # (wrap_step decorators do not preserve the AOT surface).
+        # (wrap_step decorators do not preserve the AOT surface).  Each jitted
+        # step's function name is its program's name on the device trace's
+        # ``XLA Modules`` line: ``jit_serve_decode``, ``jit_serve_prefill``,
+        # ``jit_serve_chunk``, ``jit_serve_verify``.
         self._decode_jit = (
-            jax.jit(decode_fn, donate_argnums=(1,)) if use_jit else decode_fn
+            jax.jit(serve_decode, donate_argnums=(1,))
+            if use_jit else serve_decode
         )
         self._decode = self._wrap(self._decode_jit)
 
@@ -246,8 +257,13 @@ class MegaServe:
                 cfg, collector, block_size=serve_cfg.block_size,
                 paged_flags=self.kv.paged, impl=serve_cfg.paged_attn_impl,
             )
+
+            def serve_verify(params, pool, tables, tokens, pos):
+                return spec_fn(params, pool, tables, tokens, pos)
+
             self._spec_jit = (
-                jax.jit(spec_fn, donate_argnums=(1,)) if use_jit else spec_fn
+                jax.jit(serve_verify, donate_argnums=(1,))
+                if use_jit else serve_verify
             )
             self._spec_step = self._wrap(self._spec_jit)
 
@@ -340,15 +356,15 @@ class MegaServe:
                 paged_flags=self.kv.paged, impl=serve_cfg.paged_attn_impl,
             )
 
-            def chunk_step(params, pool, tables, tokens, pos, n_last):
+            def serve_chunk(params, pool, tables, tokens, pos, n_last):
                 pool, logits, caps = chunk_fn(
                     params, pool, tables, tokens, pos, n_last
                 )
                 return pool, jnp.argmax(logits, -1), caps
 
             self._chunk_jit = (
-                jax.jit(chunk_step, donate_argnums=(1,))
-                if use_jit else chunk_step
+                jax.jit(serve_chunk, donate_argnums=(1,))
+                if use_jit else serve_chunk
             )
             self._chunk_step = self._wrap(self._chunk_jit)
 
@@ -429,7 +445,7 @@ class MegaServe:
         if self._flash_fn is not None:
             flash = self._flash_fn
 
-            def prefill_fn(params, tokens, n_real, pool, slot, phys):
+            def serve_prefill(params, tokens, n_real, pool, slot, phys):
                 # the padded block list *is* the slot's table row: the
                 # kernel writes K/V straight into those pool blocks
                 pool, logits, caps = flash(
@@ -438,7 +454,7 @@ class MegaServe:
                 return pool, jnp.argmax(logits, -1), caps
         else:
 
-            def prefill_fn(params, tokens, n_real, pool, slot, phys):
+            def serve_prefill(params, tokens, n_real, pool, slot, phys):
                 filled, logits, caps = self._slot_prefill(
                     params, tokens, n_real, cache_len
                 )
@@ -446,8 +462,8 @@ class MegaServe:
                 return pool, jnp.argmax(logits, -1), caps
 
         return (
-            jax.jit(prefill_fn, donate_argnums=(3,))
-            if self._use_jit else prefill_fn
+            jax.jit(serve_prefill, donate_argnums=(3,))
+            if self._use_jit else serve_prefill
         )
 
     def _make_seg_driver(self, n_tokens: int) -> Callable:
@@ -467,12 +483,12 @@ class MegaServe:
             cache = lm.init_cache(self.cfg, 1, cache_len)
             off, logits, caps = 0, None, {}
             for w in segs:
-                exe = self._aot_exec.get(("seg", w, cache_len), self._seg_step)
+                exe = self._exec(("seg", w, cache_len), self._seg_step)
                 cache, logits, caps = exe(
                     params, cache, tokens[:, off:off + w], jnp.int32(off)
                 )
                 off += w
-            fin = self._aot_exec.get(("seg_fin", n_blk), self._seg_finish)
+            fin = self._exec(("seg_fin", n_blk), self._seg_finish)
             pool, tok = fin(cache, logits, pool, slot, phys)
             return pool, tok, caps
 
@@ -487,55 +503,99 @@ class MegaServe:
         if self._seg_ok:
             fn = self._make_seg_driver(n_tokens)
         else:
+            self._note_fallback(("prefill", key))
             fn = self._wrap(self._build_prefill_jit(n_blk))
         self._prefill_cache[key] = fn
         return fn
+
+    def _exec(self, key: tuple, fallback: Callable) -> Callable:
+        """The executable ``precompile()`` built for ``key``, else the jitted
+        ``fallback``, which compiles on its first call."""
+        exe = self._aot_exec.get(key)
+        if exe is None:
+            self._note_fallback(key)
+            return fallback
+        return exe
+
+    def _note_fallback(self, key: tuple) -> None:
+        """A tick dispatches a step ``precompile()`` did not build, so it
+        compiles mid-serving: the first such dispatch of each key counts in
+        ``serve.step_fallbacks.<path>`` and leaves a ``step_fallback``
+        marker (its ``path`` and ``width``) in the trace.  Before
+        ``precompile()`` every step compiles on first use and nothing is
+        counted."""
+        if not self._precompiled or key in self._fell_back:
+            return
+        self._fell_back.add(key)
+        path, width = key[0], key[1]
+        if self.registry is not None:
+            self.registry.counter(self._m(f"step_fallbacks.{path}")).inc()
+        self.tracer.instant("step_fallback", path=path, width=width)
 
     def _m(self, name: str) -> str:
         return self._mpfx + name
 
     # --------------------------------------------------------------- step
     def step(self) -> dict:
-        """One scheduler tick; returns what happened for observability."""
+        """One scheduler tick; returns what happened for observability.  A
+        tick with no live slot and no arrived request has nothing to do: it
+        returns at once and traces nothing, so a server polling for
+        arrivals leaves no spans."""
         now = self._clock()
+        nxt = self.sched.next_arrival()
+        if not self.sched.active_slots() and (nxt is None or nxt > now):
+            return {"admitted": [], "preempted": [], "finished": [],
+                    "active": 0, "tokens": 0}
+        with self.tracer.scope("tick", kind="host", step=self.step_idx):
+            return self._tick(now)
+
+    def _tick(self, now: float) -> dict:
         admitted, tokens_out = [], 0
         chunk_min = (
             self.serve_cfg.resolved_chunk_len
             if self._chunk_step is not None else None
         )
 
-        for adm in self.sched.admit(now):
-            if self.registry is not None and not adm.is_recompute:
-                wait = self.sched.requests[adm.rid].queue_wait
-                if wait is not None:
-                    self.registry.histogram(
-                        self._m("queue_wait_s")).observe(wait)
+        with self.tracer.scope("admit", kind="host", step=self.step_idx):
+            admissions = self.sched.admit(now)
+        for adm in admissions:
+            admitted.append(adm.rid)
             n_real = len(adm.tokens)
-            if chunk_min is not None and n_real > chunk_min:
-                # long prompt: don't stall this tick on a monolithic prefill
-                # — stream it chunk-by-chunk (first chunk runs just below),
-                # with decode ticks interleaving until the last chunk lands
-                self._chunking[adm.slot] = {
-                    "rid": adm.rid, "toks": list(adm.tokens),
-                    "written": 0, "t0": now,
-                }
-                admitted.append(adm.rid)
-                continue
-            fn = self._prefill_for(n_real)
-            toks, phys = list(adm.tokens), list(adm.phys)
-            n_blk = self._prefill_blocks(n_real)
-            if self._pad_prefill:
-                # right-pad tokens to the bucketed cache length and the block
-                # list to the bucket width with null-block entries (their
-                # garbage K/V land in block 0, which every read masks out)
-                toks += [0] * (n_blk * self.serve_cfg.block_size - n_real)
-                phys += [0] * (n_blk - len(phys))
-            elif self._seg_ok:
-                # segment driver: tokens stay exact (recurrent state must
-                # integrate every real position, none invented), but the
-                # block list pads to the bucketed scatter width
-                phys += [0] * (n_blk - len(phys))
-            tokens = jnp.asarray(toks, jnp.int32)[None, :]
+            with self.tracer.scope("admit", kind="host", rid=adm.rid,
+                                   slot=adm.slot, step=self.step_idx):
+                if self.registry is not None and not adm.is_recompute:
+                    wait = self.sched.requests[adm.rid].queue_wait
+                    if wait is not None:
+                        self.registry.histogram(
+                            self._m("queue_wait_s")).observe(wait)
+                if chunk_min is not None and n_real > chunk_min:
+                    # long prompt: don't stall this tick on a monolithic
+                    # prefill — stream it chunk-by-chunk (first chunk runs
+                    # just below), with decode ticks interleaving until the
+                    # last chunk lands
+                    self._chunking[adm.slot] = {
+                        "rid": adm.rid, "toks": list(adm.tokens),
+                        "written": 0, "t0": now,
+                    }
+                    continue
+                fn = self._prefill_for(n_real)
+                toks, phys = list(adm.tokens), list(adm.phys)
+                n_blk = self._prefill_blocks(n_real)
+                if self._pad_prefill:
+                    # right-pad tokens to the bucketed cache length and the
+                    # block list to the bucket width with null-block entries
+                    # (their garbage K/V land in block 0, which every read
+                    # masks out)
+                    toks += [0] * (n_blk * self.serve_cfg.block_size - n_real)
+                    phys += [0] * (n_blk - len(phys))
+                elif self._seg_ok:
+                    # segment driver: tokens stay exact (recurrent state must
+                    # integrate every real position, none invented), but the
+                    # block list pads to the bucketed scatter width
+                    phys += [0] * (n_blk - len(phys))
+                tokens = jnp.asarray(toks, jnp.int32)[None, :]
+                n_arr, slot_arr = jnp.int32(n_real), jnp.int32(adm.slot)
+                phys_arr = jnp.asarray(phys, jnp.int32)
             t_pre = self._clock()
             with self.tracer.scope(
                 "prefill", kind="compute", rid=adm.rid, slot=adm.slot,
@@ -543,8 +603,7 @@ class MegaServe:
                 step=self.step_idx,
             ):
                 self.pool, tok, caps = fn(
-                    self.params, tokens, jnp.int32(n_real), self.pool,
-                    jnp.int32(adm.slot), jnp.asarray(phys, jnp.int32),
+                    self.params, tokens, n_arr, self.pool, slot_arr, phys_arr,
                 )
                 tok = jax.block_until_ready(tok)
             now = self._clock()
@@ -556,7 +615,6 @@ class MegaServe:
                     ttft = self.sched.requests[adm.rid].ttft
                     if ttft is not None:
                         self.registry.histogram(self._m("ttft_s")).observe(ttft)
-            admitted.append(adm.rid)
             tokens_out += 1
 
         # one chunk per chunking slot per tick; a completed last chunk
@@ -602,7 +660,8 @@ class MegaServe:
         # preempted alone still publishes: ensure_capacity can evict every
         # slot (pool too tight for even one), and that count must not vanish
         if self.registry is not None and (admitted or active or preempted):
-            self._publish_tick(active, preempted, tokens_out)
+            with self.tracer.scope("publish", kind="host"):
+                self._publish_tick(active, preempted, tokens_out)
         return {
             "admitted": admitted,
             "preempted": preempted,
@@ -637,12 +696,12 @@ class MegaServe:
                 pow2_bucket(blocks_for(w + C, bs)), scfg.max_blocks_per_slot
             )
             tables = jnp.asarray(self.sched.tables[slot : slot + 1, :width])
+            fn = self._exec(("chunk", width), self._chunk_step)
             t0 = self._clock()
             with self.tracer.scope(
                 "prefill_chunk", kind="compute", rid=st["rid"], slot=slot,
                 offset=w, tokens=min(C, n_real - w), step=self.step_idx,
             ):
-                fn = self._aot_exec.get(("chunk", width), self._chunk_step)
                 self.pool, tok, caps = fn(
                     self.params, self.pool, tables,
                     jnp.asarray(chunk, jnp.int32)[None, :],
@@ -696,15 +755,16 @@ class MegaServe:
 
     def _decode_tick(self, active: list[int]) -> int:
         """One plain fused decode step over every active slot (1 token each)."""
-        toks = jnp.asarray(self.sched.last_tok, jnp.int32)
-        pos = jnp.asarray(self.sched.pos, jnp.int32)
-        tables = self._live_tables(active)
+        with self.tracer.scope("upload", kind="host"):
+            toks = jnp.asarray(self.sched.last_tok, jnp.int32)
+            pos = jnp.asarray(self.sched.pos, jnp.int32)
+            tables = self._live_tables(active)
+        fn = self._exec(("decode", tables.shape[1]), self._decode)
         t_dec = self._clock()
         with self.tracer.scope(
             "decode", kind="compute", step=self.step_idx,
             active=len(active), tokens=len(active),
         ):
-            fn = self._aot_exec.get(("decode", tables.shape[1]), self._decode)
             self.pool, next_tok, caps = fn(
                 self.params, self.pool, tables, toks, pos
             )
@@ -712,12 +772,13 @@ class MegaServe:
         now = self._clock()
         if self.registry is not None:
             self.registry.histogram(self._m("decode_step_s")).observe(now - t_dec)
-        next_tok = np.asarray(next_tok)
-        for s in active:
-            self.sched.advance(s)
-            self._emit(s, int(next_tok[s]), caps,
-                       slot_axis=(self.decode_path == "gathered"))
-            self.sched.record_token(s, int(next_tok[s]), now)
+        with self.tracer.scope("pull", kind="host", active=len(active)):
+            next_tok = np.asarray(next_tok)
+            for s in active:
+                self.sched.advance(s)
+                self._emit(s, int(next_tok[s]), caps,
+                           slot_axis=(self.decode_path == "gathered"))
+                self.sched.record_token(s, int(next_tok[s]), now)
         return len(active)
 
     # --------------------------------------------------------- speculation
@@ -778,18 +839,25 @@ class MegaServe:
         high-water mark (``Scheduler.trim_blocks``)."""
         scfg = self.serve_cfg
         Q = scfg.spec_k + 1
-        toks = np.zeros((scfg.num_slots, Q), np.int32)
-        for s in active:
-            row = [self.sched.last_tok[s]] + drafts.get(s, [])
-            toks[s, : len(row)] = row
-        pos = jnp.asarray(self.sched.pos, jnp.int32)
-        tables = self._live_tables(active)
+        with self.tracer.scope("upload", kind="host"):
+            rows = np.zeros((scfg.num_slots, Q), np.int32)
+            for s in active:
+                row = [self.sched.last_tok[s]] + drafts.get(s, [])
+                rows[s, : len(row)] = row
+            toks = jnp.asarray(rows)
+            pos = jnp.asarray(self.sched.pos, jnp.int32)
+            tables = self._live_tables(active)
+        fn = self._exec(("verify", tables.shape[1]), self._spec_step)
+        drafted = sum(len(d) for d in drafts.values())
         v0 = self._clock()
-        fn = self._aot_exec.get(("verify", tables.shape[1]), self._spec_step)
-        self.pool, greedy, _logits, caps = fn(
-            self.params, self.pool, tables, jnp.asarray(toks), pos
-        )
-        greedy = np.asarray(jax.block_until_ready(greedy))
+        # the verify TraceEvent is recorded below, once acceptance knows its
+        # token count; the profiler span brackets the device call itself
+        with self.tracer.annotate("verify", step=self.step_idx,
+                                  active=len(active), drafted=drafted):
+            self.pool, greedy, _logits, caps = fn(
+                self.params, self.pool, tables, toks, pos
+            )
+            greedy = np.asarray(jax.block_until_ready(greedy))
         now = self._clock()
         v_dur = now - v0
         t0 = now
@@ -836,8 +904,7 @@ class MegaServe:
         # entry); ts/dur still bracket exactly the jitted verification
         self.tracer.record(
             "verify", v0, v_dur, kind="compute", step=self.step_idx,
-            active=len(active), tokens=emitted_total,
-            drafted=sum(len(d) for d in drafts.values()),
+            active=len(active), tokens=emitted_total, drafted=drafted,
         )
         self.tracer.record(
             "accept", t0, self._clock() - t0, kind="host",
@@ -846,7 +913,6 @@ class MegaServe:
         if self.registry is not None:
             reg = self.registry
             reg.histogram(self._m("verify_step_s")).observe(v_dur)
-            drafted = sum(len(d) for d in drafts.values())
             if drafted:
                 reg.counter(self._m("spec_proposed")).inc(drafted)
                 reg.counter(self._m("spec_accepted")).inc(accepted_total)
@@ -1115,6 +1181,7 @@ class MegaServe:
             for p in ("decode", "prefill", "chunk", "verify"):
                 self.registry.gauge(
                     self._m(f"precompile_ms.{p}")).set(out[p]["ms"])
+        self._precompiled = True
         return out
 
     # -------------------------------------------------------------- drain

@@ -524,7 +524,13 @@ def test_trace_events_and_scope_captures(qwen_serve):
     kinds = {e.name for e in events}
     assert {"prefill", "decode"} <= kinds
     for e in events:
-        assert e.dur >= 0 and e.args.get("tokens", 0) >= 1
+        assert e.dur >= 0
+        # device calls carry their token count; the tick's host spans
+        # (tick, admit, upload, pull) carry none
+        if e.kind == "compute":
+            assert e.args.get("tokens", 0) >= 1
+        else:
+            assert e.kind == "host"
     doc = to_chrome(events)                         # MegaScan-compatible
     assert doc["traceEvents"]
 

@@ -109,7 +109,9 @@ def test_flash_prefill_compiles_for_v5e(one_chip, H, K, dh):
 def test_served_steps_compile_for_v5e_at_qwen2_width(one_chip, step):
     """MegaServe's own decode step and its 512-token flash prefill bucket at
     the full width of qwen2-0.5b: the served prefill must reach the flash
-    kernel (a decode kernel at Q = 512 overruns VMEM)."""
+    kernel (a decode kernel at Q = 512 overruns VMEM).  The compiled program
+    carries the names a device trace shows: the module ``jit_serve_<step>``
+    and the Pallas kernel it runs."""
     from repro.configs import get_config
     from repro.models import get_model
     from repro.serve import MegaServe, ServeConfig
@@ -140,5 +142,7 @@ def test_served_steps_compile_for_v5e_at_qwen2_width(one_chip, step):
             pa, i32(1, width * BS), i32(), pool, i32(), i32(width))
     txt = lowered.compile().as_text()
     assert "tpu_custom_call" in txt
-    if step == "prefill":
-        assert "paged_prefill_pallas" in txt
+    assert f"HloModule jit_serve_{step}" in txt
+    kernel = {"decode": "paged_attention_pallas",
+              "prefill": "paged_prefill_pallas"}[step]
+    assert kernel in txt
