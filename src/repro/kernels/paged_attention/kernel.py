@@ -5,11 +5,11 @@ block pool: no dense ``[S, max_len, ...]`` view is ever materialized.  Layout:
 
     q       [S, H, dh] or [S, Q, H, dh]   Q query tokens per slot (Q > 1 is
                                           the speculative-decoding verify step)
-    k_pool  [(n_layers,) num_blocks, bs, K, dh]   the physical pool
-    v_pool  [(n_layers,) num_blocks, bs, K, dv]   (see PagedKVCache)
+    k_pool  [(n_layers,) num_blocks, bs, K*dh]    the physical pool
+    v_pool  [(n_layers,) num_blocks, bs, K*dv]    (see PagedKVCache)
     tables  [S, M] int32          per-slot block tables (padding -> null 0)
     kv_len  [S] int32             live positions per slot (incl. all Q tokens)
-    layer   scalar int32          pool layer for the 5-D layer-stacked layout
+    layer   scalar int32          pool layer for the 4-D layer-stacked layout
                                   (rides scalar prefetch into the index maps,
                                   so the stacked pool is never sliced in HBM)
 
@@ -24,16 +24,18 @@ every fetched K/V block: multi-token verification costs the same HBM traffic
 as single-token decode.
 
 TPU layout.  Mosaic lowers only 2-D (or leading-batch) matmuls and wants
-every block's last two dims either (8, 128)-aligned or whole, so the wrapper
-re-lays both sides around the KV head:
+every block's last two dims either (8, 128)-aligned or whole, so both sides
+are laid out around the KV head:
 
 * q rides as ``[S, K, Q*G, dh]`` — KV head leading, the ``G`` query heads of
   that group times the ``Q`` query tokens on the row axis (row ``r`` is query
   ``r // G``);
-* the pool is viewed as ``[(n,) num_blocks, bs, K*dh]`` (a free reshape of
-  the trailing ``[K, dh]``), so one table entry DMAs one dense
-  ``(bs, K*dh)`` tile and KV head ``h`` is the lane slice
-  ``[h*dh, (h+1)*dh)``.
+* the pool is *stored* as ``[(n,) num_blocks, bs, K*dh]`` (``PagedKVCache``
+  flattens the trailing ``[K, dh]``) and taken as it is, so one table entry
+  DMAs one dense ``(bs, K*dh)`` tile and KV head ``h`` is the lane slice
+  ``[h*dh, (h+1)*dh)``.  ``K`` and ``dv`` follow from the shapes
+  (``K*dh`` over q's ``dh``).  Reshaping a ``[.., K, dh]`` pool here instead
+  is not free on the TPU: it relayouts the whole pool at every call.
 
 The score and value matmuls then run per KV head as plain 2-D
 ``[Q*G, dh] x [bs, dh]^T`` and ``[Q*G, bs] x [bs, dv]`` products.
@@ -145,24 +147,26 @@ def _paged_kernel(
 )
 def paged_attention_pallas(
     q: jax.Array,        # [S, H, dh] or [S, Q, H, dh]
-    k_pool: jax.Array,   # [(n,) num_blocks, bs, K, dh]
-    v_pool: jax.Array,   # [(n,) num_blocks, bs, K, dv]
+    k_pool: jax.Array,   # [(n,) num_blocks, bs, K*dh]
+    v_pool: jax.Array,   # [(n,) num_blocks, bs, K*dv]
     tables: jax.Array,   # [S, M] int32
     kv_len: jax.Array,   # [S] int32
     *,
     scale: float,
     window: int | None = None,
     interpret: bool = False,
-    layer: jax.Array | None = None,  # indexes layer-stacked 5-D pools
+    layer: jax.Array | None = None,  # indexes layer-stacked 4-D pools
 ) -> jax.Array:
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, None]
     S, Q, H, dh = q.shape
-    if k_pool.ndim == 4:  # single-layer pool: lift to the stacked layout
+    if k_pool.ndim == 3:  # single-layer pool: lift to the stacked layout
         k_pool, v_pool = k_pool[None], v_pool[None]
         layer = jnp.zeros((), jnp.int32)
-    n, nb, bs, K, dv = v_pool.shape
+    bs = k_pool.shape[2]
+    K = k_pool.shape[-1] // dh
+    dv = v_pool.shape[-1] // K
     M = tables.shape[1]
     G = H // K
     assert K * G == H, (H, K)
@@ -173,8 +177,6 @@ def paged_attention_pallas(
     # every fetched K/V block is scored against all Q*G rows at once
     qk = q.reshape(S, Q, K, G, dh).transpose(0, 2, 1, 3, 4)
     qk = qk.reshape(S, K, Q * G, dh)
-    kp = k_pool.reshape(n, nb, bs, K * dh)
-    vp = v_pool.reshape(n, nb, bs, K * dv)
 
     def kv_map(s, j, tbl, kvl, lay):
         # clamp dead entries onto the live range [first, last]: same index as
@@ -217,7 +219,7 @@ def paged_attention_pallas(
         # the kernel's name on the device trace, which the benchmark's
         # roofline reader matches
         name="paged_attention_pallas",
-    )(tables, kv_len, lay, qk, kp, vp)
+    )(tables, kv_len, lay, qk, k_pool, v_pool)
     o = out.reshape(S, K, Q, G, dv).transpose(0, 2, 1, 3, 4)
     o = o.reshape(S, Q, H, dv).astype(q.dtype)
     return o[:, 0] if squeeze else o

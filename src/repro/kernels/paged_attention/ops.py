@@ -3,8 +3,9 @@
 ``PagedInfo`` is the small pytree the serving engine threads through
 ``lm.forward`` down to ``layers.attention`` to flip a block from the dense
 cached path onto the paged pool: the block's cache leaves then *are* pool
-arrays ``[num_blocks, bs, *feat]`` and attention walks ``tables`` instead of
-a gathered dense view.
+arrays ``[num_blocks, bs, F]`` (``F`` the flattened feature axes, ``K*dh``
+for k/v; see ``PagedKVCache``) and attention walks ``tables`` instead of a
+gathered dense view.
 """
 
 from __future__ import annotations
@@ -31,7 +32,7 @@ class PagedInfo:
     kernel dispatch choice.
 
     ``layer``, when set, marks the cache leaves as *whole layer-stacked*
-    pools ``[n_layers, num_blocks, bs, *feat]`` indexed at that layer —
+    pools ``[n_layers, num_blocks, bs, F]`` indexed at that layer —
     ``lm.forward`` threads the stacked pools through its scan carry (updated
     in place via layer-indexed scatters) instead of slicing them into scan
     xs/ys, which would re-stack the full pool every decode step."""
@@ -65,15 +66,15 @@ class PagedInfo:
 def paged_attention(
     q: jax.Array,        # [S, Q, H, dh] (model layout; Q > 1 = spec-decode
                          #   verify) or [S, H, dh] (bare single-token)
-    k_pool: jax.Array,   # [(n_layers,) num_blocks, bs, K, dh]
-    v_pool: jax.Array,   # [(n_layers,) num_blocks, bs, K, dv]
+    k_pool: jax.Array,   # [(n_layers,) num_blocks, bs, K*dh]
+    v_pool: jax.Array,   # [(n_layers,) num_blocks, bs, K*dv]
     *,
     tables: jax.Array,   # [S, M] int32
     kv_len: jax.Array,   # [S] int32 (live positions incl. all Q new tokens)
     scale: float,
     window: int | None = None,
     impl: str = "auto",
-    layer: jax.Array | None = None,  # required for layer-stacked (5-D) pools
+    layer: jax.Array | None = None,  # required for layer-stacked (4-D) pools
 ) -> jax.Array:
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
@@ -90,8 +91,8 @@ def paged_prefill(
     q: jax.Array,        # [S, Q, H, dh] raw post-projection queries
     kk: jax.Array,       # [S, Q, K, dh] raw post-projection keys
     vv: jax.Array,       # [S, Q, K, dv] values
-    k_pool: jax.Array,   # [(n_layers,) num_blocks, bs, K, dh]
-    v_pool: jax.Array,   # [(n_layers,) num_blocks, bs, K, dv]
+    k_pool: jax.Array,   # [(n_layers,) num_blocks, bs, K*dh]
+    v_pool: jax.Array,   # [(n_layers,) num_blocks, bs, K*dv]
     *,
     tables: jax.Array,   # [S, M] int32
     positions: jax.Array,  # [S, Q] int32 contiguous write positions per slot
@@ -137,8 +138,11 @@ def paged_prefill(
     phys = jnp.take_along_axis(tables, blk, axis=1)          # [S, Q]
     phys = jnp.where(in_reach, phys, 0)
     off = pos % bs
-    k_new = kk.astype(jnp.bfloat16).astype(k_pool.dtype)
-    v_new = vv.astype(jnp.bfloat16).astype(v_pool.dtype)
+    # [S, Q, K*d] rows: the pool stores each position's heads flattened
+    k_new = kk.reshape(*kk.shape[:2], -1).astype(jnp.bfloat16)
+    v_new = vv.reshape(*vv.shape[:2], -1).astype(jnp.bfloat16)
+    k_new = k_new.astype(k_pool.dtype)
+    v_new = v_new.astype(v_pool.dtype)
     if layer is None:
         ck = k_pool.at[phys, off].set(k_new)
         cv = v_pool.at[phys, off].set(v_new)

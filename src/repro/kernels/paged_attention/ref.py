@@ -28,24 +28,26 @@ NEG = -1e30
 
 def paged_attention_ref(
     q: jax.Array,        # [S, H, dh] or [S, Q, H, dh]
-    k_pool: jax.Array,   # [(n,) num_blocks, bs, K, dh]
-    v_pool: jax.Array,   # [(n,) num_blocks, bs, K, dv]
+    k_pool: jax.Array,   # [(n,) num_blocks, bs, K*dh]
+    v_pool: jax.Array,   # [(n,) num_blocks, bs, K*dv]
     tables: jax.Array,   # [S, M] int32
     kv_len: jax.Array,   # [S] int32, live positions incl. all Q new tokens
     *,
     scale: float,
     window: int | None = None,
-    layer: jax.Array | None = None,  # indexes layer-stacked 5-D pools
+    layer: jax.Array | None = None,  # indexes layer-stacked 4-D pools
 ) -> jax.Array:
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, None]
     S, Q, H, dh = q.shape
-    bs, K, dv = v_pool.shape[-3:]
+    bs = k_pool.shape[-2]
+    K = k_pool.shape[-1] // dh
+    dv = v_pool.shape[-1] // K
     M = tables.shape[1]
     G = H // K
     flat = tables.reshape(-1)
-    if k_pool.ndim == 5:
+    if k_pool.ndim == 4:
         # one fused (layer, block) gather — never materializes a layer slice
         k = k_pool[layer, flat]
         v = v_pool[layer, flat]
@@ -77,8 +79,8 @@ def paged_attention_ref(
 
 def paged_prefill_ref(
     q: jax.Array,        # [S, Q, H, dh], already normed + roped
-    k_pool: jax.Array,   # [(n,) num_blocks, bs, K, dh]
-    v_pool: jax.Array,   # [(n,) num_blocks, bs, K, dv]
+    k_pool: jax.Array,   # [(n,) num_blocks, bs, K*dh]
+    v_pool: jax.Array,   # [(n,) num_blocks, bs, K*dv]
     tables: jax.Array,   # [S, M] int32
     kv_len: jax.Array,   # [S] int32, live positions incl. all Q new tokens
     *,
@@ -111,7 +113,7 @@ def paged_prefill_ref(
     call, window masks included.
     """
     S, Q, H, dh = q.shape
-    bs = v_pool.shape[-3]
+    bs = v_pool.shape[-2]
     M = tables.shape[1]
     qb = q_block if (q_block and Q % q_block == 0) else Q
     qb = min(qb, Q)

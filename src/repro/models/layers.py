@@ -219,7 +219,7 @@ def attention(
 ) -> jax.Array:
     if paged is not None:
         # paged-KV decode: k/v are the *physical block pool* ``[num_blocks,
-        # bs, K, D]`` and the kernel walks ``paged.tables`` instead of a
+        # bs, K*D]`` and the kernel walks ``paged.tables`` instead of a
         # gathered dense view — S == 1, per-slot ``kv_len`` masks dead
         # positions.  (No ``attn_probs`` tag on this path: probabilities
         # never materialize outside the kernel.)
@@ -512,7 +512,7 @@ def gqa_apply(
     new_cache = None
     if cache is not None and paged is not None:
         # paged decode: cache leaves are the physical pool ``[(n_layers,)
-        # num_blocks, bs, K, dh]`` shared by all slots; the new tokens' K/V
+        # num_blocks, bs, K*dh]`` shared by all slots; the new tokens' K/V
         # go *straight into the blocks owning each slot's write positions*
         # (no dense gather, no block write-back).  S > 1 is the speculative-
         # decoding verify step: S = draft_len + 1 tokens land at consecutive
@@ -534,8 +534,11 @@ def gqa_apply(
         phys = jnp.take_along_axis(paged.tables, blk, axis=1)  # [B, S]
         phys = jnp.where(in_reach, phys, 0)
         off = pos % bs
-        k_new = kk.astype(jnp.bfloat16).astype(cache["k"].dtype)
-        v_new = vv.astype(jnp.bfloat16).astype(cache["v"].dtype)
+        # [B, S, K*dh] rows: the pool stores a position's heads flattened
+        k_new = kk.reshape(B, S, -1).astype(jnp.bfloat16)
+        v_new = vv.reshape(B, S, -1).astype(jnp.bfloat16)
+        k_new = k_new.astype(cache["k"].dtype)
+        v_new = v_new.astype(cache["v"].dtype)
         if paged.layer is None:
             ck = cache["k"].at[phys, off].set(k_new)
             cv = cache["v"].at[phys, off].set(v_new)

@@ -11,7 +11,17 @@ over layers exactly once, i.e. shaped ``[n_layers, batch, ...]``.  Leaves
 whose post-batch axis is the full-length ``kv_time`` axis (k/v, ckv/kpe,
 griffin window k/v) are *paged*:
 
-    dense leaf  [n, B, L_max, *feat]   ->   pool [n, num_blocks, bs, *feat]
+    dense leaf  [n, B, L_max, *feat]   ->   pool [n, num_blocks, bs, F]
+
+with ``F = prod(feat)``: the trailing feature axes are stored flattened
+(``K*dh`` for GQA k/v; MLA's one-axis ``ckv``/``kpe`` keep their shape).
+That is the ``(bs, K*dh)`` tile the paged kernels DMA per table entry, so
+the pool is stored as the kernels read it: a pool kept as ``[.., K, dh]``
+and reshaped at each kernel call is a relayout of the whole pool on the TPU
+(a ``[K, dh]`` minor tile pads a head size of 64 to 128 lanes), paid in
+every layer of every step.  The dense views (``gather``, the inputs of the
+prefill / decode scatters, ``export_slot`` bundles) keep the model's
+``[.., *feat]`` axes.
 
 All other leaves (rwkv wkv/x_prev, griffin conv/h — O(1) recurrent state per
 slot, nothing to page) are *slot-state* leaves stored densely per slot:
@@ -37,6 +47,7 @@ Two decode paths share this pool (``server.ServeConfig.decode_path``):
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Any
 
@@ -161,17 +172,20 @@ class PagedKVCache:
             return leaf.shape[ax.index("kv_time")] == L
 
         self.paged = jax.tree.map(is_paged, template, axes)
+        # the dense cache's leaf shapes: a paged leaf's feature axes are
+        # ``shape[3:]`` here, flattened into the pool's last axis
+        self.dense = template
 
         def make_pool(leaf, paged):
             n = leaf.shape[0]
-            feat = leaf.shape[3:] if paged else leaf.shape[2:]
             dtype = leaf.dtype
             if paged and promote_store and dtype == jnp.bfloat16:
                 dtype = jnp.float32
             if paged:
-                shape = (n, spec.num_blocks, spec.block_size, *feat)
+                shape = (n, spec.num_blocks, spec.block_size,
+                         math.prod(leaf.shape[3:]))
             else:
-                shape = (n, spec.num_slots, *feat)
+                shape = (n, spec.num_slots, *leaf.shape[2:])
             return jnp.zeros(shape, dtype)
 
         self.pool = jax.tree.map(make_pool, template, self.paged)
@@ -187,14 +201,14 @@ class PagedKVCache:
         S, M = tables.shape
         bs = self.spec.block_size
 
-        def leaf(p, paged):
+        def leaf(p, d, paged):
             if not paged:
                 return p
             n = p.shape[0]
-            g = jnp.take(p, tables.reshape(-1), axis=1)       # [n, S*M, bs, f]
-            return g.reshape(n, S, M * bs, *p.shape[3:])
+            g = jnp.take(p, tables.reshape(-1), axis=1)       # [n, S*M, bs, F]
+            return g.reshape(n, S, M * bs, *d.shape[3:])
 
-        return jax.tree.map(leaf, pool, self.paged)
+        return jax.tree.map(leaf, pool, self.dense, self.paged)
 
     # ------------------------------------------------- scatter (decode)
     def scatter_decode(
@@ -216,7 +230,8 @@ class PagedKVCache:
                 return jax.lax.dynamic_slice_in_dim(d_s, start, bs, axis=1)
 
             blocks = jax.vmap(pick, in_axes=(1, 0), out_axes=1)(d, tb * bs)
-            return p.at[:, phys].set(blocks)                   # [n, S, bs, f]
+            return p.at[:, phys].set(                          # [n, S, bs, F]
+                blocks.reshape(*blocks.shape[:3], -1))
 
         return jax.tree.map(leaf, pool, dense, self.paged)
 
@@ -224,18 +239,20 @@ class PagedKVCache:
     def export_slot(self, pool: Any, phys: jax.Array, slot: jax.Array) -> Any:
         """Pull one slot's cache state out of the pool as a self-contained
         bundle — the disaggregation hand-off unit.  Paged leaves become
-        ``[n, n_blk, bs, *feat]`` (the slot's blocks in table order);
+        ``[n, n_blk, bs, *feat]`` (the slot's blocks in table order, in the
+        dense cache's feature axes);
         slot-state leaves become ``[n, *feat]`` (the slot's row).  ``phys``
         may be padded with null-block entries: the padding rows carry
         whatever the null block holds and are ignored on import.
         """
 
-        def leaf(p, paged):
+        def leaf(p, d, paged):
             if paged:
-                return jnp.take(p, phys, axis=1)
+                b = jnp.take(p, phys, axis=1)                  # [n, n_blk, bs, F]
+                return b.reshape(*b.shape[:3], *d.shape[3:])
             return p[:, slot]
 
-        return jax.tree.map(leaf, pool, self.paged)
+        return jax.tree.map(leaf, pool, self.dense, self.paged)
 
     # ------------------------------------------- slot migration (import)
     def import_slot(
@@ -248,6 +265,7 @@ class PagedKVCache:
 
         def leaf(p, b, paged):
             if paged:
+                b = b.reshape(*b.shape[:3], -1)
                 return p.at[:, phys].set(b.astype(p.dtype))
             return p.at[:, slot].set(b.astype(p.dtype))
 
@@ -266,7 +284,7 @@ class PagedKVCache:
             if not paged:
                 return p.at[:, slot].set(f[:, 0])
             n = p.shape[0]
-            r = f[:, 0].reshape(n, n_blk, bs, *p.shape[3:])
+            r = f[:, 0].reshape(n, n_blk, bs, -1)             # [n, n_blk, bs, F]
             return p.at[:, phys].set(r)
 
         return jax.tree.map(leaf, pool, filled, self.paged)

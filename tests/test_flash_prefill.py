@@ -30,7 +30,8 @@ def _prefill_case(S, Q, H, K, dh, bs, M, kv_lens, *, window=None,
     (o_xla, o_pallas, o_fulltable) plus the scattered pools for comparison."""
     rng = np.random.default_rng(seed)
     n_blocks = 40
-    shape = ((3,) if layered else ()) + (n_blocks, bs, K, dh)
+    # the pool as PagedKVCache stores it: heads flattened, [.., bs, K*dh]
+    shape = ((3,) if layered else ()) + (n_blocks, bs, K * dh)
     k_pool = jnp.asarray(rng.standard_normal(shape), jnp.float32)
     v_pool = jnp.asarray(rng.standard_normal(shape), jnp.float32)
     tbl = np.zeros((S, M), np.int32)

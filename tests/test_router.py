@@ -369,6 +369,14 @@ def test_kv_export_import_roundtrip_bit_identical(qwen_router):
 
     phys = jnp.asarray([3, 5, 0, 0], jnp.int32)
     bundle = kv.export_slot(pool, phys, jnp.int32(1))
+    # the pool stores heads flattened ([n, blocks, bs, K*dh]); a bundle
+    # carries the slot's blocks in the dense cache's [.., K, dh] axes
+    for b, p, d in zip(jax.tree.leaves(bundle), jax.tree.leaves(pool),
+                       jax.tree.leaves(kv.dense)):
+        assert p.shape == (d.shape[0], 9, 8, d.shape[3] * d.shape[4])
+        assert b.shape == (d.shape[0], 4, 8, *d.shape[3:])
+        np.testing.assert_array_equal(
+            np.asarray(b), np.asarray(p)[:, np.asarray(phys)].reshape(b.shape))
     # import into a different slot/blocks of a different pool
     pool2 = jax.tree.map(
         lambda p: jax.random.normal(next(key), p.shape).astype(p.dtype),

@@ -112,6 +112,27 @@ def test_paged_prefill_gather_roundtrip(qwen_serve):
                                           np.asarray(f[:, 0]))
 
 
+@pytest.mark.parametrize("arch", [
+    "qwen2-0.5b", "deepseek-v2-lite-16b", "recurrentgemma-9b", "rwkv6-3b"])
+def test_pool_stores_flattened_feature_axes(arch):
+    """Paged leaves are stored ``[n, num_blocks, bs, prod(feat)]`` (GQA k/v
+    as ``K*dh``, the tile the paged kernels read; MLA's one-axis latents
+    unchanged); slot-state leaves keep ``[n, num_slots, *feat]``."""
+    import math
+
+    cfg = get_config(arch, smoke=True)
+    spec = PoolSpec(num_slots=2, num_blocks=9, block_size=8, max_blocks=4)
+    kv = PagedKVCache(cfg, spec)
+    for p, d, paged in zip(jax.tree.leaves(kv.pool), jax.tree.leaves(kv.dense),
+                           jax.tree.leaves(kv.paged)):
+        n = d.shape[0]
+        if paged:
+            assert p.shape == (n, 9, 8, math.prod(d.shape[3:]))
+            assert p.ndim == 4
+        else:
+            assert p.shape == (n, 2, *d.shape[2:])
+
+
 def test_scatter_decode_touches_only_written_block(qwen_serve):
     cfg, _ = qwen_serve
     spec = PoolSpec(num_slots=2, num_blocks=9, block_size=8, max_blocks=4)
@@ -141,15 +162,17 @@ def test_scatter_decode_touches_only_written_block(qwen_serve):
 def _rand_paged(seed, S, bs, K, G, dh, kv_lens):
     """Random pool + block tables + queries for ``S`` slots with ragged
     ``kv_lens``; every slot gets distinct physical blocks, padding entries
-    point at the null block 0 (which holds garbage, as in live serving)."""
+    point at the null block 0 (which holds garbage, as in live serving).
+    The single-layer pool is ``[nb, bs, K*dh]``, as ``PagedKVCache`` stores
+    it."""
     rng = np.random.default_rng(seed)
     live = [blocks_for(int(l), bs) for l in kv_lens]
     M = max(live)
     nb = 1 + sum(live)
     H = K * G
     q = jnp.asarray(rng.standard_normal((S, H, dh)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((nb, bs, K, dh)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((nb, bs, K, dh)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((nb, bs, K * dh)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((nb, bs, K * dh)), jnp.float32)
     tables = np.zeros((S, M), np.int32)
     perm = rng.permutation(np.arange(1, nb))
     i = 0
@@ -207,7 +230,7 @@ def test_paged_ref_matches_gathered_dense_oracle():
 
 
 def test_paged_kernel_layer_stacked_pool():
-    """The 5-D layer-stacked pool layout (what the serving scan carries) must
+    """The 4-D layer-stacked pool layout (what the serving scan carries) must
     match slicing the layer out by hand, on both ref and interpret kernel."""
     from repro.kernels.paged_attention import (
         paged_attention_pallas,
@@ -219,14 +242,14 @@ def test_paged_kernel_layer_stacked_pool():
     )
     n_layers = 3
     rng = np.random.default_rng(12)
-    kp5 = jnp.asarray(rng.standard_normal((n_layers, *kp.shape)), jnp.float32)
-    vp5 = jnp.asarray(rng.standard_normal((n_layers, *vp.shape)), jnp.float32)
+    kp4 = jnp.asarray(rng.standard_normal((n_layers, *kp.shape)), jnp.float32)
+    vp4 = jnp.asarray(rng.standard_normal((n_layers, *vp.shape)), jnp.float32)
     for g in (0, 2):
-        want = paged_attention_ref(q, kp5[g], vp5[g], tables, kv_len, scale=0.25)
+        want = paged_attention_ref(q, kp4[g], vp4[g], tables, kv_len, scale=0.25)
         got_ref = paged_attention_ref(
-            q, kp5, vp5, tables, kv_len, scale=0.25, layer=jnp.int32(g))
+            q, kp4, vp4, tables, kv_len, scale=0.25, layer=jnp.int32(g))
         got_ker = paged_attention_pallas(
-            q, kp5, vp5, tables, kv_len, scale=0.25, layer=jnp.int32(g),
+            q, kp4, vp4, tables, kv_len, scale=0.25, layer=jnp.int32(g),
             interpret=True)
         np.testing.assert_allclose(np.asarray(got_ref), np.asarray(want), atol=2e-6)
         np.testing.assert_allclose(np.asarray(got_ker), np.asarray(want), atol=2e-6)
