@@ -27,6 +27,15 @@ class MoEConfig:
                                     # model-axis activation sharding)
     router_aux_coef: float = 0.001  # load-balance loss coefficient
     router_z_coef: float = 0.0001   # router z-loss coefficient
+    norm_topk_prob: bool = True     # renormalise the top-k gates to sum 1
+    routed_scaling_factor: float = 1.0  # multiplies the routed experts' gates
+    experts_held: int = 0           # routed experts this device holds, from
+    expert_offset: int = 0          # this one: an expert-parallel chip's
+                                    # share (0 held = all of them)
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -36,6 +45,18 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class RopeScaling:
+    """YaRN context extension (Peng et al. 2023, arXiv:2309.00071) as
+    DeepSeek-V2 publishes it (``rope_scaling`` of its ``config.json``)."""
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -73,6 +94,7 @@ class ModelConfig:
     qk_norm: bool = False
     rope_theta: float = 10000.0
     mrope_sections: tuple[int, ...] = ()      # (t, h, w) halves; empty = 1-D RoPE
+    rope_scaling: RopeScaling | None = None   # YaRN; None = plain rope
     use_mla: bool = False
     mla: MLAConfig = field(default_factory=MLAConfig)
     # MoE

@@ -1,14 +1,22 @@
 """deepseek-v2-lite-16b [moe] — 27L d_model=2048 16H d_ff(expert)=1408 vocab=102400.
 
 MLA (kv_lora_rank=512, no query compression) + MoE: 64 routed experts top-6 and
-2 shared experts [arXiv:2405.04434].  The assignment line mentions both
-"MoE 64e top-6" and "2 shared+160 routed"; the published V2-Lite config is
-64 routed + 2 shared, which matches "64e" and the HF checkpoint — used here
-(see DESIGN.md §7).  Layer 0 uses a dense MLP (first_k_dense_replace=1,
-intermediate size 10944 per HF config).
+2 shared experts [arXiv:2405.04434; hf:deepseek-ai/DeepSeek-V2-Lite
+config.json].  Layer 0 uses a dense MLP (first_k_dense_replace=1,
+intermediate size 10944).  The router is a float32 softmax over all 64
+experts, greedy top-6, gates not renormalised (norm_topk_prob false),
+routed_scaling_factor 1.  Rope is YaRN: factor 40 over 4096 original
+positions (163840 in all), beta_fast 32, beta_slow 1, mscale and
+mscale_all_dim 0.707.
 """
 
-from repro.configs.base import MLAConfig, ModelConfig, MoEConfig, register
+from repro.configs.base import (
+    MLAConfig,
+    ModelConfig,
+    MoEConfig,
+    RopeScaling,
+    register,
+)
 
 CONFIG = ModelConfig(
     name="deepseek-v2-lite-16b",
@@ -35,9 +43,19 @@ CONFIG = ModelConfig(
         expert_d_ff=1408,
         capacity_factor=1.25,
         first_k_dense=1,
+        norm_topk_prob=False,
+        routed_scaling_factor=1.0,
     ),
     rope_theta=10000.0,
-    notes="MLA compressed-KV cache at decode; EP over the model axis.",
+    rope_scaling=RopeScaling(
+        factor=40.0,
+        original_max_position_embeddings=4096,
+        beta_fast=32.0,
+        beta_slow=1.0,
+        mscale=0.707,
+        mscale_all_dim=0.707,
+    ),
+    notes="MLA latent paged cache at decode; EP over the model axis.",
 )
 
 SMOKE_CONFIG = CONFIG.replace(
@@ -63,6 +81,15 @@ SMOKE_CONFIG = CONFIG.replace(
         expert_d_ff=64,
         capacity_factor=1.5,
         first_k_dense=1,
+        norm_topk_prob=False,
+    ),
+    rope_scaling=RopeScaling(
+        factor=40.0,
+        original_max_position_embeddings=64,
+        beta_fast=32.0,
+        beta_slow=1.0,
+        mscale=0.707,
+        mscale_all_dim=0.707,
     ),
     attn_kv_chunk=32,
     logits_chunk=16,

@@ -17,9 +17,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.paged_attention.kernel import paged_attention_pallas
+from repro.kernels.paged_attention.latent_kernel import (
+    paged_latent_attention_pallas,
+)
 from repro.kernels.paged_attention.prefill_kernel import paged_prefill_pallas
 from repro.kernels.paged_attention.ref import (
     paged_attention_ref,
+    paged_latent_attention_ref,
     paged_prefill_ref,
 )
 
@@ -85,6 +89,29 @@ def paged_attention(
         q, k_pool, v_pool, tables, kv_len, scale=scale, window=window,
         layer=layer,
     )
+
+
+def paged_latent_attention(
+    q: jax.Array,         # [S, H, W]  [q_nope W_uk | q_pe | zeros]
+    pool: jax.Array,      # [(n_layers,) num_blocks, bs, W]
+    *,
+    tables: jax.Array,    # [S, M] int32
+    kv_len: jax.Array,    # [S] int32
+    r: int,               # value width (kv_lora_rank)
+    scale: float,
+    impl: str = "auto",
+    layer: jax.Array | None = None,
+) -> jax.Array:
+    """Absorbed MLA decode against the latent pool: the float32 context
+    ``[S, H, r]`` (``latent_kernel.py``; the XLA ref off the TPU)."""
+    if impl == "auto":
+        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+    if impl == "xla":
+        return paged_latent_attention_ref(
+            q, pool, tables, kv_len, r=r, scale=scale, layer=layer)
+    return paged_latent_attention_pallas(
+        q, pool, tables, kv_len, r=r, scale=scale,
+        interpret=(impl == "pallas_interpret"), layer=layer)
 
 
 def paged_prefill(

@@ -128,3 +128,33 @@ def paged_prefill_ref(
             scale=scale, window=window, layer=layer,
         ))
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
+def paged_latent_attention_ref(
+    q: jax.Array,         # [S, H, W]  [q_nope W_uk | q_pe | zeros]
+    pool: jax.Array,      # [(n,) num_blocks, bs, W]  [ckv | k_pe | zeros]
+    tables: jax.Array,    # [S, M] int32
+    kv_len: jax.Array,    # [S] int32
+    *,
+    r: int,
+    scale: float,
+    layer: jax.Array | None = None,
+) -> jax.Array:
+    """Oracle for the latent decode kernel (``latent_kernel.py``): gathers
+    each slot's table out of the latent pool and mirrors the gathered
+    path's absorbed attention in ``models.layers.mla_apply`` (scores
+    ``q_lat ckv^T + q_pe k_pe^T`` as one product over the row, masked
+    softmax, context over ``ckv``, each einsum in the query's dtype with
+    float32 accumulation).  Returns the float32 context ``[S, H, r]``."""
+    S, H, W = q.shape
+    bs = pool.shape[-2]
+    M = tables.shape[1]
+    flat = tables.reshape(-1)
+    rows = pool[layer, flat] if pool.ndim == 4 else jnp.take(pool, flat, 0)
+    rows = rows.reshape(S, M * bs, W).astype(q.dtype)
+    s = jnp.einsum("shw,stw->sht", q, rows,
+                   preferred_element_type=jnp.float32) * scale
+    live = jnp.arange(M * bs)[None, None, :] < kv_len[:, None, None]
+    p = jax.nn.softmax(jnp.where(live, s, NEG), axis=-1)
+    return jnp.einsum("sht,str->shr", p.astype(q.dtype), rows[..., :r],
+                      preferred_element_type=jnp.float32)

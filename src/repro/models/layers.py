@@ -132,19 +132,59 @@ def rms_head_norm(scale: jax.Array, x: jax.Array, eps: float) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def rope_freqs(dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term, ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def yarn_ramp_range(dim: int, theta: float, scaling) -> tuple[int, int]:
+    """The frequency indices between which YaRN blends extrapolated into
+    interpolated frequencies: floor / ceil of the dimensions that turn
+    ``beta_fast`` / ``beta_slow`` times over the original context."""
+
+    def corr_dim(rotations: float) -> float:
+        n = scaling.original_max_position_embeddings
+        return (dim * math.log(n / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = math.floor(corr_dim(scaling.beta_fast))
+    high = math.ceil(corr_dim(scaling.beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def rope_freqs(dim: int, theta: float, scaling=None) -> jax.Array:
+    """Inverse frequencies of rope; with ``scaling`` (a ``RopeScaling``) the
+    YaRN blend: index i keeps ``theta^(-2i/dim)`` below the ramp, takes it
+    divided by ``factor`` above it, and mixes the two linearly between."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if scaling is None:
+        return freqs
+    low, high = yarn_ramp_range(dim, theta, scaling)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling=None) -> jax.Array:
     """x [B, S, H, D] or [B, S, D]; positions [S] shared or [B, S] per-row
-    absolute positions (the paged decode step carries one position per slot)."""
+    absolute positions (the paged decode step carries one position per slot).
+    ``scaling`` (a ``RopeScaling``) switches to YaRN frequencies, with cos and
+    sin multiplied by ``mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)``."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)  # [d/2]
+    freqs = rope_freqs(d, theta, scaling)  # [d/2]
     ang = positions[..., None].astype(jnp.float32) * freqs  # [(B,) S, d/2]
     if x.ndim == 4:  # head dim present: [B, S, H, D]
         ang = ang[..., None, :]  # [(B,) S, 1, d/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -623,12 +663,119 @@ def mla_init(b: ParamBuilder, cfg: ModelConfig):
             fan_in=H * m.v_head_dim, scale=1.0 / math.sqrt(2 * cfg.num_layers))
 
 
+def mla_cache_width(cfg: ModelConfig) -> int:
+    """Width of one cached token of MLA: ``[ckv | k_pe]`` rounded up to a
+    lane multiple (576 -> 640 for DeepSeek-V2), the row the latent kernel
+    DMAs per block; the tail is zero."""
+    m = cfg.mla
+    return -(-(m.kv_lora_rank + m.qk_rope_head_dim) // 128) * 128
+
+
+def _latent_row(cfg: ModelConfig, ckv: jax.Array, kpe: jax.Array) -> jax.Array:
+    """``[ckv | k_pe | zeros]``: one cache row per token."""
+    pad = mla_cache_width(cfg) - ckv.shape[-1] - kpe.shape[-1]
+    zeros = jnp.zeros((*ckv.shape[:-1], pad), ckv.dtype)
+    return jnp.concatenate([ckv, kpe.astype(ckv.dtype), zeros], axis=-1)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``(qk_nope + qk_rope)^-0.5``, times ``mscale(factor, mscale_all_dim)^2``
+    under YaRN (DeepSeek-V2's ``softmax_scale``)."""
+    m = cfg.mla
+    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_qkr(p, cfg, x, positions):
     m = cfg.mla
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
     qn = q[..., : m.qk_nope_head_dim]
-    qr = apply_rope(q[..., m.qk_nope_head_dim :], positions, cfg.rope_theta)
+    qr = apply_rope(q[..., m.qk_nope_head_dim :], positions, cfg.rope_theta,
+                    cfg.rope_scaling)
     return qn, qr
+
+
+def _mla_expanded(p, cfg, qn, qr, ckv, kpe, positions, scale, collector):
+    """Attention with K and V up-projected per head from the latent (the
+    training and whole-prompt prefill form): q = [q_nope, q_pe], k =
+    [ckv W_uk, k_pe] (k_pe shared by every head), v = ckv W_uv."""
+    m = cfg.mla
+    B, S, H, _ = qn.shape
+    dt = qn.dtype
+    kn = jnp.einsum("bsr,rhk->bshk", ckv, p["wuk"].astype(dt))
+    vv = jnp.einsum("bsr,rhv->bshv", ckv, p["wuv"].astype(dt))
+    k_full = jnp.concatenate(
+        [kn, jnp.broadcast_to(kpe[:, :, None, :], (B, S, H, m.qk_rope_head_dim))],
+        axis=-1,
+    )
+    q_full = jnp.concatenate([qn, qr], axis=-1)
+    q_full = shard_act(q_full, ("batch", "seq", "heads_act", "head_dim_act"))
+    k_full = shard_act(k_full, ("batch", "seq_kv", "heads_act", "head_dim_act"))
+    vv = shard_act(vv, ("batch", "seq_kv", "heads_act", "head_dim_act"))
+    o = attention(
+        q_full, k_full, vv,
+        scale=scale,
+        positions_q=positions,
+        causal=True,
+        impl=cfg.attn_impl,
+        kv_chunk=cfg.attn_kv_chunk,
+        collector=collector,
+    )
+    return jnp.einsum("bshv,hvd->bsd", o, p["wo"].astype(dt))
+
+
+def _mla_paged(p, cfg, qn, qr, ckv, kpe, cache, positions, paged, scale,
+               collector):
+    """MLA against the latent paged pool ``latent`` ``[(n,) blocks, bs, W]``
+    (rows ``[ckv | k_pe | zeros]``), which takes the new rows in place (the
+    same block/offset scatter as ``gqa_apply``'s paged branch).  A decode step
+    (one query per slot) attends in the latent space through the paged
+    latent kernel: ``[q_nope W_uk | q_pe]`` scores against the rows, the
+    context accumulates over ``ckv``, then ``W_uv`` and ``W_o``.  A
+    whole-prompt prefill (``paged.prefill`` with ``q_start == 0``) attends
+    expanded over the prompt itself, then leaves its rows in the pool."""
+    m = cfg.mla
+    S = qn.shape[1]
+    dt = qn.dtype
+    whole_prompt = paged.prefill and paged.q_start == 0
+    if not whole_prompt and (S > 1 or paged.prefill):
+        raise NotImplementedError(
+            "the latent (MLA) pool serves one-token decode and whole-prompt "
+            "prefill; chunked prefill and speculative verification would need "
+            "a latent multi-query kernel")
+    pos = positions                                  # [B, S] write positions
+    bs = paged.block_size
+    in_reach = pos < paged.tables.shape[1] * bs
+    blk = jnp.where(in_reach, pos // bs, 0)
+    phys = jnp.take_along_axis(paged.tables, blk, axis=1)
+    phys = jnp.where(in_reach, phys, 0)
+    off = pos % bs
+    # rows quantize through bfloat16 (the cache dtype) even in a wider
+    # container, as the GQA pool does
+    pool = cache["latent"]
+    row = _latent_row(cfg, ckv, kpe).astype(jnp.bfloat16).astype(pool.dtype)
+    if paged.layer is None:
+        pool = pool.at[phys, off].set(row)
+    else:
+        pool = pool.at[paged.layer, phys, off].set(row)
+    if whole_prompt:
+        out = _mla_expanded(p, cfg, qn, qr, ckv, kpe, positions, scale,
+                            collector)
+        return out, {"latent": pool}
+    from repro.kernels.paged_attention.ops import paged_latent_attention
+
+    q_lat = jnp.einsum("bhk,rhk->bhr", qn[:, 0], p["wuk"].astype(dt))
+    q = _latent_row(cfg, q_lat, qr[:, 0])            # [B, H, W]
+    ctx = paged_latent_attention(
+        q, pool, tables=paged.tables, kv_len=pos[:, 0] + 1,
+        r=m.kv_lora_rank, scale=scale, impl=paged.impl, layer=paged.layer,
+    )                                                # [B, H, r] f32
+    o = jnp.einsum("bhr,rhv->bhv", ctx.astype(dt), p["wuv"].astype(dt))
+    out = jnp.einsum("bhv,hvd->bd", o, p["wo"].astype(dt))
+    return out[:, None], {"latent": pool}
 
 
 def mla_apply(
@@ -637,37 +784,36 @@ def mla_apply(
     x: jax.Array,
     *,
     positions: jax.Array,
-    cache: dict | None = None,  # {"ckv": [B,T,r], "kpe": [B,T,dr]}
+    cache: dict | None = None,  # {"latent": [B, T, W]} rows, or the pool
     cache_pos: jax.Array | None = None,
-    paged: Any | None = None,
+    paged: Any | None = None,  # PagedInfo: cache leaves are latent pools
     collector: Collector = NULL_COLLECTOR,
 ) -> tuple[jax.Array, dict | None]:
-    if paged is not None:
-        # the latent-space cache has no kv-head axis for the paged kernel to
-        # walk; MLA serves through the gathered-dense oracle path instead
-        raise NotImplementedError("paged decode does not support MLA")
     m = cfg.mla
-    B, S, D = x.shape
-    H = cfg.num_heads
-    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    S = x.shape[1]
+    scale = mla_softmax_scale(cfg)
     qn, qr = _mla_qkr(p, cfg, x, positions)
     ckv = jnp.einsum("bsd,dr->bsr", x, p["wdkv"].astype(x.dtype))
     ckv = norm_apply({"scale": p["kv_norm"]}, ckv, "rmsnorm", cfg.norm_eps)
     kpe = apply_rope(
         jnp.einsum("bsd,dr->bsr", x, p["wkr"].astype(x.dtype)), positions,
-        cfg.rope_theta,
+        cfg.rope_theta, cfg.rope_scaling,
     )
+    if paged is not None:
+        return _mla_paged(p, cfg, qn, qr, ckv, kpe, cache, positions, paged,
+                          scale, collector)
 
     if cache is not None and S == 1:
         # absorbed decode: attend in the latent space (compressed KV cache)
-        ckv_c = jax.lax.dynamic_update_slice_in_dim(
-            cache["ckv"], ckv.astype(cache["ckv"].dtype), cache_pos, axis=1)
-        kpe_c = jax.lax.dynamic_update_slice_in_dim(
-            cache["kpe"], kpe.astype(cache["kpe"].dtype), cache_pos, axis=1)
-        new_cache = {"ckv": ckv_c, "kpe": kpe_c}
-        ckv_s = shard_act(ckv_c, ("batch", "kv_time", "kv_lora_act"))
-        kpe_s = shard_act(kpe_c, ("batch", "kv_time", "head_dim_act"))
-        T = ckv_s.shape[1]
+        lat = jax.lax.dynamic_update_slice_in_dim(
+            cache["latent"], _latent_row(cfg, ckv, kpe).astype(
+                cache["latent"].dtype), cache_pos, axis=1)
+        new_cache = {"latent": lat}
+        lat = shard_act(lat, ("batch", "kv_time", "kv_lora_act"))
+        r = m.kv_lora_rank
+        ckv_s = lat[..., :r]
+        kpe_s = lat[..., r:r + m.qk_rope_head_dim]
+        T = lat.shape[1]
         q_lat = jnp.einsum("bshk,rhk->bshr", qn, p["wuk"].astype(x.dtype))
         s = (
             jnp.einsum("bshr,btr->bsht", q_lat, ckv_s.astype(x.dtype),
@@ -687,34 +833,13 @@ def mla_apply(
         return out, new_cache
 
     # full (training / prefill) path
-    kn = jnp.einsum("bsr,rhk->bshk", ckv, p["wuk"].astype(x.dtype))
-    vv = jnp.einsum("bsr,rhv->bshv", ckv, p["wuv"].astype(x.dtype))
-    k_full = jnp.concatenate(
-        [kn, jnp.broadcast_to(kpe[:, :, None, :], (B, S, H, m.qk_rope_head_dim))],
-        axis=-1,
-    )
-    q_full = jnp.concatenate([qn, qr], axis=-1)
-    q_full = shard_act(q_full, ("batch", "seq", "heads_act", "head_dim_act"))
-    k_full = shard_act(k_full, ("batch", "seq_kv", "heads_act", "head_dim_act"))
-    vv = shard_act(vv, ("batch", "seq_kv", "heads_act", "head_dim_act"))
-    o = attention(
-        q_full, k_full, vv,
-        scale=scale,
-        positions_q=positions,
-        causal=True,
-        impl=cfg.attn_impl,
-        kv_chunk=cfg.attn_kv_chunk,
-        collector=collector,
-    )
-    out = jnp.einsum("bshv,hvd->bsd", o, p["wo"].astype(x.dtype))
+    out = _mla_expanded(p, cfg, qn, qr, ckv, kpe, positions, scale, collector)
     new_cache = None
     if cache is not None:  # prefill fills the compressed cache
-        T = cache["ckv"].shape[1]
-        pad = [(0, 0), (0, T - S), (0, 0)]
-        new_cache = {
-            "ckv": jnp.pad(ckv.astype(cache["ckv"].dtype), pad),
-            "kpe": jnp.pad(kpe.astype(cache["kpe"].dtype), pad),
-        }
+        lat = cache["latent"]
+        row = _latent_row(cfg, ckv, kpe).astype(lat.dtype)
+        new_cache = {"latent": jnp.pad(
+            row, [(0, 0), (0, lat.shape[1] - S), (0, 0)])}
     return out, new_cache
 
 
@@ -756,11 +881,17 @@ def mlp_apply(p: dict, cfg: ModelConfig, x: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
 def moe_init(b: ParamBuilder, cfg: ModelConfig):
+    """The router keeps all ``num_experts`` outputs; the expert weights are
+    the ``held`` experts this device computes (``MoEConfig.experts_held``,
+    all of them unless set)."""
     D = cfg.d_model
     mo = cfg.moe
-    E, F = mo.num_experts, mo.expert_d_ff
-    b.param("router", (D, E), ("embed_w", None), fan_in=D)
+    E, F = mo.held, mo.expert_d_ff
+    b.param("router", (D, mo.num_experts), ("embed_w", None), fan_in=D)
     b.param("w_gate", (E, D, F), ("expert_w", "embed_w", "expert_mlp"), fan_in=D)
     b.param("w_up", (E, D, F), ("expert_w", "embed_w", "expert_mlp"), fan_in=D)
     b.param("w_down", (E, F, D), ("expert_w", "expert_mlp", "embed_w"),
@@ -771,7 +902,122 @@ def moe_init(b: ParamBuilder, cfg: ModelConfig):
         mlp_init(s, shared_cfg, d_ff=mo.num_shared_experts * F)
 
 
+def _route(p: dict, cfg: ModelConfig, x: jax.Array):
+    """Router over all ``num_experts``: float32 logits, softmax, greedy
+    top-k; gates renormalised only where ``norm_topk_prob``, then scaled by
+    ``routed_scaling_factor``.  Returns (logits, probs, gate, expert ids)."""
+    mo = cfg.moe
+    logits = jnp.einsum(
+        "...d,de->...e", x.astype(jnp.float32),
+        p["router"].astype(jnp.float32), precision=jax.lax.Precision.HIGHEST,
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate, eidx = jax.lax.top_k(probs, mo.top_k)
+    if mo.norm_topk_prob:
+        gate = gate / jnp.clip(gate.sum(-1, keepdims=True), 1e-9)
+    if mo.routed_scaling_factor != 1.0:
+        gate = gate * mo.routed_scaling_factor
+    return logits, probs, gate, eidx
+
+
+def _router_aux(mo, logits, probs, eidx):
+    """Switch-style load-balance loss and router z-loss over every token."""
+    E = mo.num_experts
+    me = probs.reshape(-1, E).mean(0)
+    ce = jnp.zeros((E,), jnp.float32).at[eidx.reshape(-1)].add(1.0) / eidx.size
+    aux_lb = (me * ce).sum() * E * mo.router_aux_coef
+    aux_z = jnp.square(jax.nn.logsumexp(logits, axis=-1)).mean() * mo.router_z_coef
+    return aux_lb + aux_z
+
+
+def _shared_experts(p: dict, x: jax.Array) -> jax.Array:
+    """The shared experts: a plain SwiGLU that every token passes through."""
+    hs = jnp.einsum("...d,df->...f", x, p["w_up"].astype(x.dtype))
+    gs = jnp.einsum("...d,df->...f", x, p["w_gate"].astype(x.dtype))
+    return jnp.einsum("...f,fd->...d", jax.nn.silu(gs) * hs,
+                      p["w_down"].astype(x.dtype))
+
+
+def expert_parallel_degree(num_experts: int) -> int:
+    """How many devices the installed mesh spreads the experts over (the mesh
+    axes the ``expert_act`` rule gives an expert axis of ``num_experts``)."""
+    from repro.parallel.sharding import current_mesh_and_rules, logical_to_spec
+
+    mesh, rules = current_mesh_and_rules()
+    if mesh is None:
+        return 1
+    spec = logical_to_spec(("expert_act",), (num_experts,), mesh, rules)
+    axes = spec[0] if len(spec) else None
+    if axes is None:
+        return 1
+    axes = (axes,) if isinstance(axes, str) else axes
+    return math.prod(mesh.shape[a] for a in axes)
+
+
 def moe_apply(
+    p: dict,
+    cfg: ModelConfig,
+    x: jax.Array,  # [B, S, D]
+    *,
+    n_seq_groups: int = 1,
+    collector: Collector = NULL_COLLECTOR,
+    layer: jax.Array | None = None,
+) -> tuple[jax.Array, dict[str, jax.Array]]:
+    """Routed experts plus shared experts.
+
+    With ``layer`` set, the routed experts' weights (``EXPERT_WEIGHTS``) come
+    stacked over the segment's layers and this is layer ``layer`` of them
+    (``lm.forward`` passes them so: the grouped kernel reads its layer in
+    place, where a per-layer slice would be copied on every step).
+
+    Where the mesh spreads the experts over several devices the layer keeps
+    the capacity dispatch and its all-to-all (``_moe_capacity``).  Otherwise
+    it is one device's share of an expert-parallel layer, dropless: it holds
+    the experts ``[expert_offset, expert_offset + experts_held)`` (all of
+    them unless ``MoEConfig`` says otherwise), routes over all
+    ``num_experts``, sorts the picks that land on its experts and runs them
+    through one grouped matmul (``kernels.moe.expert_ffn``).  Picks on
+    experts it does not hold add nothing here (another device computes
+    them); the shared experts are added once.  A token's result never
+    depends on what else shares the batch.
+    """
+    mo = cfg.moe
+    if expert_parallel_degree(mo.num_experts) > 1:
+        if layer is not None:
+            p = {**p, **{k: p[k][layer] for k in EXPERT_WEIGHTS}}
+        return _moe_capacity(p, cfg, x, n_seq_groups=n_seq_groups,
+                             collector=collector)
+    from repro.kernels.moe import expert_ffn
+
+    B, S, D = x.shape
+    K, Eh = mo.top_k, mo.held
+    xt = x.reshape(B * S, D)
+    logits, probs, gate, eidx = _route(p, cfg, xt)     # [T, K]
+    gate = collector.tag("router_gate", gate)
+    local = eidx - mo.expert_offset
+    held = (local >= 0) & (local < Eh)
+    key = jnp.where(held, local, Eh).reshape(-1)        # [T*K]; absent last
+    order = jnp.argsort(key, stable=True)
+    counts = jnp.zeros((Eh + 1,), jnp.int32).at[key].add(1)[:Eh]
+    xs = jnp.take(xt, order // K, axis=0)               # picks, held first
+    ys = expert_ffn(xs, p["w_gate"], p["w_up"], p["w_down"], counts,
+                    layer=layer)
+    back = jnp.argsort(order)                           # pick -> sorted row
+    yk = jnp.take(ys, back, axis=0).reshape(B * S, K, D)
+    # a select, not a product: rows past the held picks are never written
+    w = jnp.where(held[..., None], gate[..., None] * yk.astype(jnp.float32), 0)
+    y = w.sum(1).astype(x.dtype)
+    if mo.num_shared_experts:
+        y = y + _shared_experts(p["shared"], xt)
+    aux = {
+        "moe_aux_loss": _router_aux(mo, logits, probs, eidx),
+        "moe_drop_frac": jnp.zeros((), jnp.float32),
+        "moe_held_picks": counts,
+    }
+    return y.reshape(B, S, D), aux
+
+
+def _moe_capacity(
     p: dict,
     cfg: ModelConfig,
     x: jax.Array,  # [B, S, D]
@@ -803,17 +1049,9 @@ def moe_apply(
     )
     xt = shard_act(x4.reshape(G, Cg, D), ("moe_groups", None, "embed_act"))
 
-    logits = jnp.einsum("gcd,de->gce", xt, p["router"].astype(x.dtype)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, eidx = jax.lax.top_k(probs, K)  # [G, Cg, K]
-    gate = gate / jnp.clip(gate.sum(-1, keepdims=True), 1e-9)
+    logits, probs, gate, eidx = _route(p, cfg, xt)     # [G, Cg, K]
     gate = collector.tag("router_gate", gate)
-
-    # aux losses (Switch-style load balance + z-loss)
-    me = probs.mean(axis=(0, 1))  # [E]
-    ce = jnp.zeros((E,), jnp.float32).at[eidx.reshape(-1)].add(1.0) / (G * N)
-    aux_lb = (me * ce).sum() * E * mo.router_aux_coef
-    aux_z = jnp.square(jax.nn.logsumexp(logits, axis=-1)).mean() * mo.router_z_coef
+    aux_loss = _router_aux(mo, logits, probs, eidx)
 
     cap = max(int(math.ceil(Cg * K / E * mo.capacity_factor)), 1)
 
@@ -867,19 +1105,14 @@ def moe_apply(
 
     if mo.num_shared_experts:
         # shared experts: a plain SwiGLU applied in the group-local layout
-        sp = p["shared"]
-        hs = jnp.einsum("gcd,df->gcf", xt, sp["w_up"].astype(x.dtype))
-        gs = jnp.einsum("gcd,df->gcf", xt, sp["w_gate"].astype(x.dtype))
-        y = y + jnp.einsum(
-            "gcf,fd->gcd", jax.nn.silu(gs) * hs, sp["w_down"].astype(x.dtype)
-        )
+        y = y + _shared_experts(p["shared"], xt)
 
     y = shard_act(y, ("moe_groups", None, "embed_act"))
     y4 = shard_act(
         y.reshape(B, nsg, Cg, D), ("batch", "seq_act", None, "embed_act")
     )
     aux = {
-        "moe_aux_loss": aux_lb + aux_z,
+        "moe_aux_loss": aux_loss,
         "moe_drop_frac": (slot_entry == E * cap).mean(),
     }
     return y4.reshape(B, S, D), aux

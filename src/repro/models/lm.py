@@ -111,6 +111,7 @@ def _block_apply(
     mrope_position_ids: jax.Array | None,
     paged: Any | None,
     collector: Collector,
+    layer: jax.Array | None = None,
 ) -> tuple[jax.Array, dict | None, dict]:
     # anchor the block input: the constraint's transpose pins the residual
     # *gradient* sharding in backward (GSPMD can otherwise fully replicate it
@@ -143,7 +144,8 @@ def _block_apply(
     h = L.norm_apply(p["ln2"], x, cfg.norm_kind, cfg.norm_eps)
     if kind == "moe":
         f, aux = L.moe_apply(
-            p["mlp"], cfg, h, n_seq_groups=cfg.moe.seq_groups, collector=collector
+            p["mlp"], cfg, h, n_seq_groups=cfg.moe.seq_groups,
+            collector=collector, layer=layer,
         )
     else:
         f = L.mlp_apply(p["mlp"], cfg, h, collector)
@@ -239,6 +241,24 @@ def _merge_cache(a: Any, b: Any) -> Any:
     return a if b is None else b
 
 
+def _split_experts(seg_p: dict, kinds: tuple[str, ...]) -> tuple[dict, dict]:
+    """Take the routed experts' weights of a segment's MoE blocks out of the
+    per-layer scan inputs: ``(rest, stacks)``, ``stacks[f"b{j}"]`` the
+    ``[n_layers, experts, ...]`` arrays.  The scan body hands them to the
+    expert layer whole, with the layer index: a per-layer slice of them would
+    be materialized for the grouped kernel on every step of the loop."""
+    rest, stacks = dict(seg_p), {}
+    for j, kind in enumerate(kinds):
+        if kind != "moe":
+            continue
+        blk = dict(rest[f"b{j}"])
+        mlp = dict(blk["mlp"])
+        stacks[f"b{j}"] = {k: mlp.pop(k) for k in L.EXPERT_WEIGHTS}
+        blk["mlp"] = mlp
+        rest[f"b{j}"] = blk
+    return rest, stacks
+
+
 def _embed_inputs(cfg: ModelConfig, params: dict, batch: dict, dtype) -> jax.Array:
     if cfg.input_kind == "tokens":
         return L.embed_apply(params, cfg, batch["tokens"], dtype)
@@ -260,6 +280,10 @@ def forward(
     collector: Collector = NULL_COLLECTOR,
 ) -> tuple[jax.Array, dict | None, dict]:
     """Returns (hidden [B,S,D], new_cache, aux).
+
+    ``aux["moe_held_picks"]`` (MoE families on the dropless expert layer)
+    counts the routing picks that landed on each held expert, per MoE layer:
+    ``[n_moe_layers, experts_held]`` int32.
 
     When ``paged`` (a ``kernels.paged_attention.ops.PagedInfo``) is set, the
     attention leaves of ``cache`` are layer-stacked physical pool arrays
@@ -288,10 +312,11 @@ def forward(
     aux_losses = jnp.zeros((), jnp.float32)
     aux_metrics: dict[str, jax.Array] = {}
     captures_by_seg: dict[str, dict] = {}
+    held_picks: list[jax.Array] = []   # [n, held] picks per held expert
     new_cache: dict = {}
     layer_offset = 0
     for i, (kinds, n) in enumerate(segment_layout(cfg)):
-        seg_p = params[f"seg{i}"]
+        seg_p, experts = _split_experts(params[f"seg{i}"], kinds)
         seg_cache = cache.get(f"seg{i}") if cache is not None else None
         if paged is not None and seg_cache is not None:
             seg_flags = paged_flags[f"seg{i}"]
@@ -299,7 +324,8 @@ def forward(
         else:
             seg_flags, seg_pool, seg_state = None, None, seg_cache
 
-        def body(carry, xs, kinds=kinds, offset=layer_offset, flags=seg_flags):
+        def body(carry, xs, kinds=kinds, offset=layer_offset, flags=seg_flags,
+                 experts=experts):
             xc, aux_c, pool_c = carry
             layer_p, layer_cache, g = xs
             new_layer_cache = {} if layer_cache is not None else None
@@ -318,14 +344,18 @@ def forward(
                     # carry, addressed at layer g) onto its slot-state slice
                     blk_cache = _merge_cache(pool_c[f"b{j}"], blk_cache)
                     blk_paged = replace(paged, layer=g)
+                blk_p = layer_p[f"b{j}"]
+                if f"b{j}" in experts:
+                    blk_p = {**blk_p, "mlp": {**blk_p["mlp"], **experts[f"b{j}"]}}
                 xc, c_new, aux = _block_apply(
-                    layer_p[f"b{j}"], cfg, kind, xc,
+                    blk_p, cfg, kind, xc,
                     positions=positions,
                     cache=blk_cache,
                     cache_pos=cache_pos,
                     mrope_position_ids=mrope_ids,
                     paged=blk_paged,
                     collector=col,
+                    layer=g if f"b{j}" in experts else None,
                 )
                 if pool_c is not None and c_new is not None:
                     p_new, c_new = _split_cache(c_new, flags[f"b{j}"])
@@ -335,6 +365,8 @@ def forward(
                 if aux:
                     aux_c = aux_c + aux.get("moe_aux_loss", 0.0)
                     captured["moe_drop_frac"] = aux.get("moe_drop_frac", 0.0)
+                    if "moe_held_picks" in aux:
+                        captured["moe_held_picks"] = aux["moe_held_picks"]
                 probes = col.drain()
                 if probes:
                     pre = f"b{j}/" if len(kinds) > 1 else ""
@@ -362,7 +394,10 @@ def forward(
         if cap:
             if "moe_drop_frac" in cap:
                 aux_metrics[f"seg{i}_moe_drop_frac"] = cap["moe_drop_frac"].mean()
-            rest = {k: v for k, v in cap.items() if k != "moe_drop_frac"}
+            if "moe_held_picks" in cap:
+                held_picks.append(cap["moe_held_picks"])
+            rest = {k: v for k, v in cap.items()
+                    if k not in ("moe_drop_frac", "moe_held_picks")}
             if rest:
                 captures_by_seg[f"seg{i}"] = rest
         layer_offset += n * len(kinds)
@@ -370,6 +405,8 @@ def forward(
     x = L.norm_apply(params["final_norm"], x, cfg.norm_kind, cfg.norm_eps)
     x = collector.tag("final_hidden", x)
     aux = {"aux_loss": aux_losses, **aux_metrics}
+    if held_picks:
+        aux["moe_held_picks"] = jnp.concatenate(held_picks, axis=0)
     top = collector.drain()
     if top or captures_by_seg:
         aux["captures"] = dict(captures_by_seg)
@@ -385,6 +422,7 @@ def loss_fn(
     collector: Collector = NULL_COLLECTOR,
 ) -> tuple[jax.Array, dict]:
     hidden, _, aux = forward(cfg, params, batch, collector=collector)
+    aux.pop("moe_held_picks", None)   # a serving counter, not a loss metric
     total, count = L.chunked_xent(
         params, cfg, hidden, batch["targets"], batch.get("loss_mask")
     )
@@ -410,11 +448,9 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int) -> dict:
                 elif kind in ("rec", "attn"):
                     out[f"b{j}"] = gf.griffin_init_state(cfg, kind, batch, cache_len)
                 elif cfg.use_mla:
-                    m = cfg.mla
-                    out[f"b{j}"] = {
-                        "ckv": jnp.zeros((batch, cache_len, m.kv_lora_rank), jnp.bfloat16),
-                        "kpe": jnp.zeros((batch, cache_len, m.qk_rope_head_dim), jnp.bfloat16),
-                    }
+                    out[f"b{j}"] = {"latent": jnp.zeros(
+                        (batch, cache_len, L.mla_cache_width(cfg)),
+                        jnp.bfloat16)}
                 else:
                     out[f"b{j}"] = {
                         "k": jnp.zeros(

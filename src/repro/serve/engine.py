@@ -23,8 +23,7 @@ _CACHE_AXES: dict[str, tuple[str | None, ...]] = {
     "v": ("batch", "kv_time", "kv_heads_act", "head_dim_act"),
     "ck": ("batch", "kv_time", "kv_heads_act", "head_dim_act"),
     "cv": ("batch", "kv_time", "kv_heads_act", "head_dim_act"),
-    "ckv": ("batch", "kv_time", "kv_lora_act"),
-    "kpe": ("batch", "kv_time", "head_dim_act"),
+    "latent": ("batch", "kv_time", "kv_lora_act"),   # MLA [ckv | k_pe]
     "wkv": ("batch", "heads_act", "state", "state"),
     "x_prev": ("batch", "embed_act"),
     "conv": ("batch", "conv", "mlp_act"),
@@ -97,7 +96,13 @@ def make_paged_decode_step(
 ) -> Callable:
     """Returns ``step(params, pool, tables [S, M], tokens [S], pos [S]) ->
     (pool, logits [S, V], captures)`` — one batched decode over all slots
-    straight against the physical block pool.
+    straight against the physical block pool.  A model with a dropless
+    expert layer also returns its routing picks per held expert in
+    ``captures["moe_held_picks"]`` (``[n_moe_layers, experts_held]``), which
+    the server's tick takes out before the captures reach a stream.
+
+    MLA models decode through the latent pool and the paged latent kernel
+    (``layers.mla_apply``); GQA models through the paged GQA kernel.
 
     Replaces the gathered path's ``gather -> vmap(B=1) -> scatter_decode``
     round trip: slots ride the batch axis of a single ``lm.forward`` call
@@ -117,8 +122,6 @@ def make_paged_decode_step(
     """
     if cfg.input_kind != "tokens":
         raise ValueError(f"{cfg.name}: continuous batching serves token archs")
-    if cfg.use_mla:
-        raise ValueError(f"{cfg.name}: MLA decodes via the gathered path")
     from repro.kernels.paged_attention.ops import PagedInfo
     from repro.models import layers as L
     from repro.models import lm
@@ -131,7 +134,10 @@ def make_paged_decode_step(
             paged_flags=paged_flags, collector=collector,
         )
         logits = L.logits_fn(params, cfg, hidden)[:, 0]
-        return new_pool, logits, aux.get("captures", {})
+        caps = dict(aux.get("captures", {}))
+        if "moe_held_picks" in aux:
+            caps["moe_held_picks"] = aux["moe_held_picks"]
+        return new_pool, logits, caps
 
     return step
 
@@ -168,7 +174,10 @@ def make_spec_verify_step(
     if cfg.input_kind != "tokens":
         raise ValueError(f"{cfg.name}: continuous batching serves token archs")
     if cfg.use_mla:
-        raise ValueError(f"{cfg.name}: MLA decodes via the gathered path")
+        raise ValueError(
+            f"{cfg.name}: speculative verification scores several queries "
+            "per slot, and the latent (MLA) pool has a one-query decode "
+            "kernel only")
     from repro.kernels.paged_attention.ops import PagedInfo
     from repro.models import layers as L
     from repro.models import lm
@@ -218,7 +227,10 @@ def make_chunk_prefill_step(
     if cfg.input_kind != "tokens":
         raise ValueError(f"{cfg.name}: continuous batching serves token archs")
     if cfg.use_mla:
-        raise ValueError(f"{cfg.name}: MLA decodes via the gathered path")
+        raise ValueError(
+            f"{cfg.name}: a prompt chunk attends over the slot's earlier "
+            "chunks in the pool, and the latent (MLA) pool has no multi-query "
+            "kernel; MLA prefills whole prompts")
     from repro.kernels.paged_attention.ops import PagedInfo
     from repro.models import layers as L
     from repro.models import lm
@@ -262,12 +274,12 @@ def make_flash_prefill_step(
     like the dense path's pad positions — masked by every later read,
     overwritten by the first decode write.  ``P`` (and the table width
     ``M = P / block_size``) is baked into the executable: one compile per
-    pow2 bucket, same ladder the dense path uses.
+    pow2 bucket, same ladder the dense path uses.  MLA models attend
+    expanded over the prompt and leave its latent rows in the pool
+    (``layers.mla_apply``).
     """
     if cfg.input_kind != "tokens":
         raise ValueError(f"{cfg.name}: continuous batching serves token archs")
-    if cfg.use_mla:
-        raise ValueError(f"{cfg.name}: MLA decodes via the gathered path")
     from repro.kernels.paged_attention.ops import PagedInfo
     from repro.models import layers as L
     from repro.models import lm

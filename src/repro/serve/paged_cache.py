@@ -8,13 +8,13 @@ indices, and slots with wildly different lengths share the pool densely.
 
 Layout convention (matches ``lm.init_cache``): every cache leaf is stacked
 over layers exactly once, i.e. shaped ``[n_layers, batch, ...]``.  Leaves
-whose post-batch axis is the full-length ``kv_time`` axis (k/v, ckv/kpe,
+whose post-batch axis is the full-length ``kv_time`` axis (k/v, MLA's latent,
 griffin window k/v) are *paged*:
 
     dense leaf  [n, B, L_max, *feat]   ->   pool [n, num_blocks, bs, F]
 
 with ``F = prod(feat)``: the trailing feature axes are stored flattened
-(``K*dh`` for GQA k/v; MLA's one-axis ``ckv``/``kpe`` keep their shape).
+(``K*dh`` for GQA k/v; MLA's one-axis ``latent`` rows keep their shape).
 That is the ``(bs, K*dh)`` tile the paged kernels DMA per table entry, so
 the pool is stored as the kernels read it: a pool kept as ``[.., K, dh]``
 and reshaped at each kernel call is a relayout of the whole pool on the TPU

@@ -160,10 +160,9 @@ class MegaServe:
         self._base = self._raw_clock()
         self._clock = lambda: self._raw_clock() - self._base
 
-        # decode-path selection: the paged kernel needs gqa-style k/v leaves
-        # (MLA's latent cache has no head axis to walk), and deep MegaScope
-        # probing wants the vmapped per-slot capture semantics of the oracle
-        paged_ok = not cfg.use_mla
+        # decode-path selection: deep MegaScope probing wants the vmapped
+        # per-slot capture semantics of the gathered oracle; otherwise the
+        # paged kernels (GQA, or the latent kernel for MLA) serve
         path = serve_cfg.decode_path
         if path == "auto":
             # speculative verification exists only on the paged path, so a
@@ -171,11 +170,14 @@ class MegaServe:
             if serve_cfg.spec_decode:
                 path = "paged"
             else:
-                path = "paged" if paged_ok and not self._capture else "gathered"
+                path = "paged" if not self._capture else "gathered"
         elif path not in ("paged", "gathered"):
             raise ValueError(f"unknown decode_path {serve_cfg.decode_path!r}")
-        if path == "paged" and not paged_ok:
-            raise ValueError(f"{cfg.name}: decode_path='paged' unsupported (MLA)")
+        if cfg.use_mla and prefill_only:
+            raise ValueError(
+                f"{cfg.name}: disaggregated serving hands a prefilled slot to "
+                "a decode replica; the latent (MLA) pool's hand-off is not "
+                "built (decode and whole-prompt prefill are)")
         if serve_cfg.spec_decode and path != "paged":
             raise ValueError(
                 "spec_decode requires the paged decode path "
@@ -741,6 +743,17 @@ class MegaServe:
             used / max(self.serve_cfg.usable_blocks, 1)
         )
 
+    def _publish_picks(self, picks: np.ndarray) -> None:
+        """Expert load of one decode call, from its ``[n_moe_layers,
+        experts_held]`` pick counts: ``moe.held_picks``, the picks on held
+        experts per MoE layer, and ``moe.max_expert_picks``, the most that one
+        held expert got in any layer."""
+        reg = self.registry
+        reg.histogram(self._m("moe.held_picks")).observe(
+            float(picks.sum()) / picks.shape[0])
+        reg.histogram(self._m("moe.max_expert_picks")).observe(
+            float(picks.max()))
+
     def _live_tables(self, active: list[int]) -> jax.Array:
         """Block tables for the decode/verify step.  On the paged path they
         are sliced to the live-block high-water mark (next power of two): the
@@ -774,6 +787,9 @@ class MegaServe:
             self.registry.histogram(self._m("decode_step_s")).observe(now - t_dec)
         with self.tracer.scope("pull", kind="host", active=len(active)):
             next_tok = np.asarray(next_tok)
+            picks = caps.pop("moe_held_picks", None)
+            if picks is not None and self.registry is not None:
+                self._publish_picks(np.asarray(picks))
             for s in active:
                 self.sched.advance(s)
                 self._emit(s, int(next_tok[s]), caps,

@@ -9,6 +9,8 @@ at the full width of qwen2-0.5b.  The compiler refuses what interpret mode
 accepts: misaligned block tiles, non-2-D matmuls inside a kernel, VMEM
 overruns.  At the serving cell's pool size the compiled steps are checked
 for copies of the whole KV pool (a relayout the pool's layout must avoid).
+The DeepSeek-V2-Lite cell's decode step and 8192-token prefill compile at
+the cell's sizes and must fit the chip's memory.
 
 The topology is described inside fixtures only: only one process at a time
 may load the TPU library, so describing it while a module is imported would
@@ -195,3 +197,60 @@ def test_served_steps_copy_no_whole_pool_on_v5e(one_chip, step):
     assert elems == {24 * 2049 * 16 * 2 * 64}
     assert f"HloModule jit_serve_{step}" in txt
     assert _whole_pool_copies(txt, elems.pop()) == []
+
+
+@pytest.fixture(scope="module")
+def deepseek_cell_server():
+    """MegaServe for the ``serve-deepseek-v2-lite-ep8-chat`` cell's shapes:
+    DeepSeek-V2-Lite with 8 of its 64 routed experts held, 128 slots, tables
+    of up to 560 blocks of 16, bfloat16 weights as shapes only."""
+    from repro.configs import MoEConfig, get_config
+    from repro.models import lm
+    from repro.serve import MegaServe, ServeConfig
+
+    base = get_config("deepseek-v2-lite-16b")
+    cfg = base.replace(moe=MoEConfig(**{**base.moe.__dict__,
+                                         "experts_held": 8}))
+    params = jax.eval_shape(lambda: lm.init(cfg, jax.random.PRNGKey(0)))
+    srv = MegaServe(cfg, params, ServeConfig(
+        num_slots=128, block_size=BS, num_blocks=9, max_blocks_per_slot=560,
+        paged_attn_impl="pallas", prefill_path="flash"))
+    return srv, params
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_deepseek_cell_steps_compile_and_fit_v5e(one_chip, deepseek_cell_server,
+                                                monkeypatch, step):
+    """The served decode step (128 slots, the widest table) and the
+    8192-token prefill bucket of the DeepSeek-V2-Lite cell compile for a
+    v5e with the cell's 14000-block latent pool, reach the latent decode
+    kernel and the grouped expert kernel, and fit the chip's 16 GB with more
+    than 1 GB to spare: 6.22 GB of weights, 7.74 GB of pool, and the step's
+    workspace."""
+    srv, params = deepseek_cell_server
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pa = jax.tree.map(lambda x: on_chip(x.shape, jnp.bfloat16), params)
+    pool = jax.tree.map(
+        lambda x: on_chip((x.shape[0], 14000, *x.shape[2:]), jnp.bfloat16),
+        srv.pool)
+    i32 = lambda *s: on_chip(s, jnp.int32)  # noqa: E731
+    # the steps pick their kernels by the backend; this process's is the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if step == "decode":
+        lowered = srv._decode_jit.lower(pa, pool, i32(128, 560), i32(128),
+                                        i32(128))
+        kernels = {"paged_latent_attention_pallas", "expert_gmm_pallas"}
+    else:
+        lowered = srv._build_prefill_jit(512).lower(
+            pa, i32(1, 512 * BS), i32(), pool, i32(), i32(512))
+        kernels = {"expert_gmm_pallas"}
+    compiled = lowered.compile()
+    txt = compiled.as_text()
+    assert all(k in txt for k in kernels)
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert total < 15e9, total
