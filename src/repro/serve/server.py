@@ -22,6 +22,7 @@ path for benchmarking and equivalence tests.
 
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable
@@ -33,6 +34,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.compile_cache import CompileCache, aot_compile, mesh_descriptor
 from repro.core.tracing.tracer import Tracer
+from repro.kernels.paged_attention.kernel import walk_chunks
 from repro.models import get_model
 from repro.models.hooks import Collector, NULL_COLLECTOR
 from repro.serve.engine import (
@@ -202,6 +204,16 @@ class MegaServe:
         # take ownership of the pool buffers; keeping them referenced from
         # self.kv too would pin a second full KV pool in device memory
         self.pool, self.kv.pool = self.kv.pool, None
+
+        # the GQA decode kernel's live-block walk: the chunks it walks per
+        # layer call (``serve.paged_decode_chunks``), from the host's
+        # positions; None where decode runs no paged GQA attention
+        self._walk = None
+        if path == "paged" and not cfg.use_mla and any(
+                jax.tree.leaves(self.kv.paged)):
+            self._walk = functools.partial(
+                walk_chunks, block_size=serve_cfg.block_size,
+                window=cfg.griffin.window if cfg.family == "griffin" else None)
 
         if path == "paged":
             paged_step = make_paged_decode_step(
@@ -757,8 +769,9 @@ class MegaServe:
     def _live_tables(self, active: list[int]) -> jax.Array:
         """Block tables for the decode/verify step.  On the paged path they
         are sliced to the live-block high-water mark (next power of two): the
-        kernel's sweep — and the XLA fallback's gather — then cost O(max live
-        kv_len), not O(pool max_len); bucketing keeps the compile cache at
+        decode kernel walks live blocks only at any width, but the tables'
+        upload and the XLA fallback's gather then cost O(max live kv_len),
+        not O(pool max_len); bucketing keeps the compile cache at
         O(log max_blocks)."""
         if self.decode_path != "paged":
             return jnp.asarray(self.sched.tables)
@@ -785,6 +798,10 @@ class MegaServe:
         now = self._clock()
         if self.registry is not None:
             self.registry.histogram(self._m("decode_step_s")).observe(now - t_dec)
+            if self._walk is not None:
+                kvl = np.asarray(self.sched.pos)[active] + 1
+                self.registry.histogram(self._m("paged_decode_chunks")).observe(
+                    float(self._walk(kvl, n_entries=tables.shape[1]).sum()))
         with self.tracer.scope("pull", kind="host", active=len(active)):
             next_tok = np.asarray(next_tok)
             picks = caps.pop("moe_held_picks", None)

@@ -182,9 +182,10 @@ def _rand_paged(seed, S, bs, K, G, dh, kv_lens):
     return q, kp, vp, jnp.asarray(tables), jnp.asarray(kv_lens, jnp.int32)
 
 
+@pytest.mark.parametrize("pages", [None, 1, 2, 4])
 @pytest.mark.parametrize("bs,G", [(8, 1), (8, 4), (16, 2)])
 @pytest.mark.parametrize("window", [None, 7])
-def test_paged_kernel_interpret_matches_ref(bs, G, window):
+def test_paged_kernel_interpret_matches_ref(bs, G, window, pages):
     from repro.kernels.paged_attention import (
         paged_attention_pallas,
         paged_attention_ref,
@@ -196,9 +197,76 @@ def test_paged_kernel_interpret_matches_ref(bs, G, window):
     )
     ref = paged_attention_ref(q, kp, vp, tables, kv_len, scale=0.25, window=window)
     ker = paged_attention_pallas(
-        q, kp, vp, tables, kv_len, scale=0.25, window=window, interpret=True
+        q, kp, vp, tables, kv_len, scale=0.25, window=window, pages=pages,
+        interpret=True,
     )
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=2e-6)
+
+
+# (pages, kv_lens, Q, window, extra table entries): the live walk's edges.
+# P*bs is 16 at pages 2 (bs 8), 32 at pages 4.
+_WALK_CASES = {
+    # one row, and a chunk's last row, its whole, and one past it
+    "chunk_edges": (2, [1, 15, 16, 17], 1, None, 0),
+    # a table 5 entries wide at 4 a chunk (not a multiple), and wider
+    # than the longest slot needs
+    "width_not_multiple": (4, [5, 20, 40], 1, None, 0),
+    "width_padded": (4, [5, 20, 40], 1, None, 3),
+    # a table narrower than one chunk
+    "width_under_chunk": (4, [3, 12], 1, None, 0),
+    # idle slots between long ones: each slot's last chunk starts the
+    # next slot's first copies
+    "idle_between_long": (2, [40, 1, 1, 37, 1, 50], 1, None, 0),
+    # windows that start inside a chunk, and one wider than the slot
+    "window_in_chunk": (2, [45, 30, 3, 61], 1, 13, 0),
+    # the verify step: 4 queries a slot, causal inside the query block
+    "verify_q4": (2, [4, 17, 33, 6], 4, None, 0),
+    "verify_q4_window": (2, [4, 17, 33, 46], 4, 11, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WALK_CASES))
+def test_paged_kernel_live_walk_matches_ref(case):
+    """The kernel walks each slot's live blocks only, ``pages`` table
+    entries a chunk, with copies double-buffered across chunks and slots:
+    at every edge of that walk it must agree with the XLA ref."""
+    from repro.kernels.paged_attention import (
+        paged_attention_pallas,
+        paged_attention_ref,
+    )
+
+    pages, kv_lens, Q, window, extra = _WALK_CASES[case]
+    S = len(kv_lens)
+    q, kp, vp, tables, kv_len = _rand_paged(
+        seed=len(case), S=S, bs=8, K=2, G=2, dh=16, kv_lens=kv_lens)
+    tables = jnp.pad(tables, ((0, 0), (0, extra)))
+    if Q > 1:
+        rng = np.random.default_rng(len(case))
+        q = jnp.asarray(rng.standard_normal((S, Q, *q.shape[1:])), jnp.float32)
+    ref = paged_attention_ref(q, kp, vp, tables, kv_len, scale=0.25,
+                              window=window)
+    ker = paged_attention_pallas(q, kp, vp, tables, kv_len, scale=0.25,
+                                 window=window, pages=pages, interpret=True)
+    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=2e-6)
+
+
+@pytest.mark.parametrize("window", [None, 13])
+@pytest.mark.parametrize("q_len", [1, 4])
+def test_walk_chunks_counts_live_chunks(q_len, window):
+    """``walk_chunks`` (the host's count behind ``paged_decode_chunks``)
+    gives, per slot, the chunks between the first and the last table entry
+    holding a key that some query attends."""
+    from repro.kernels.paged_attention.kernel import walk_chunks
+
+    bs, P, M = 8, 2, 9
+    kv = np.arange(1, M * bs + 1)
+    got = walk_chunks(kv, block_size=bs, n_entries=M, q_len=q_len,
+                      window=window, pages=P)
+    for n, g in zip(kv, got):
+        keys = np.arange(n)
+        lo = n - (q_len - 1) - window if window else 0
+        live = np.unique(keys[keys >= max(lo, 0)] // bs)
+        assert g == (live[-1] - live[0]) // P + 1, (n, g)
 
 
 def test_paged_ref_matches_gathered_dense_oracle():
@@ -244,13 +312,13 @@ def test_paged_kernel_layer_stacked_pool():
     rng = np.random.default_rng(12)
     kp4 = jnp.asarray(rng.standard_normal((n_layers, *kp.shape)), jnp.float32)
     vp4 = jnp.asarray(rng.standard_normal((n_layers, *vp.shape)), jnp.float32)
-    for g in (0, 2):
+    for g, pages in ((0, None), (2, None), (2, 1)):
         want = paged_attention_ref(q, kp4[g], vp4[g], tables, kv_len, scale=0.25)
         got_ref = paged_attention_ref(
             q, kp4, vp4, tables, kv_len, scale=0.25, layer=jnp.int32(g))
         got_ker = paged_attention_pallas(
             q, kp4, vp4, tables, kv_len, scale=0.25, layer=jnp.int32(g),
-            interpret=True)
+            pages=pages, interpret=True)
         np.testing.assert_allclose(np.asarray(got_ref), np.asarray(want), atol=2e-6)
         np.testing.assert_allclose(np.asarray(got_ker), np.asarray(want), atol=2e-6)
 
@@ -267,6 +335,11 @@ def test_paged_kernel_output_invariant_to_table_width():
     a = paged_attention_ref(q, kp, vp, tables, kv_len, scale=0.25)
     b = paged_attention_ref(q, kp, vp, wide, kv_len, scale=0.25)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    from repro.kernels.paged_attention import paged_attention_pallas
+
+    c, d = (paged_attention_pallas(q, kp, vp, t, kv_len, scale=0.25, pages=2,
+                                   interpret=True) for t in (tables, wide))
+    np.testing.assert_array_equal(np.asarray(c), np.asarray(d))
 
 
 # ------------------------------------------------------------- scheduler ---
@@ -429,6 +502,28 @@ def test_paged_kernel_end_to_end_greedy(qwen_serve):
     ref, _ = StaticRunner(cfg, params).run(
         [(p, 4, 0.0) for p in prompts], batch_size=1)
     assert outs == ref
+
+
+def test_paged_decode_chunks_counter(qwen_serve):
+    """Each paged decode call records ``serve.paged_decode_chunks``: the
+    chunks of 256 key rows the decode kernel walks per layer call, summed
+    over the active slots.  A 300-token prompt walks 2, a 10-token one 1."""
+    from repro.obs import MetricsRegistry
+
+    cfg, params = qwen_serve
+    rng = np.random.default_rng(4)
+    reg = MetricsRegistry()
+    srv = MegaServe(cfg, params, ServeConfig(
+        num_slots=2, block_size=8, num_blocks=48, max_blocks_per_slot=40,
+        max_prefills_per_step=2, decode_path="paged"), registry=reg)
+    for n in (300, 10):
+        srv.submit(rng.integers(2, cfg.vocab_size, size=n).tolist(), 3,
+                   arrival=0.0)
+    srv.drain()
+    snap = reg.snapshot()
+    chunks = snap["serve.paged_decode_chunks"]
+    assert chunks["count"] == snap["serve.decode_step_s"]["count"] > 0
+    assert chunks["max"] == 3 and chunks["min"] >= 1
 
 
 def test_prefill_bucketing_bounds_compile_cache(qwen_serve):

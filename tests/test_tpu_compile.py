@@ -1,6 +1,7 @@
 """The serving kernels compile for a TPU v5e at real widths.
 
-Compiles paged decode (Q = 1 and the Q = 5 verify step) and flash prefill
+Compiles paged decode (Q = 1 and the Q = 5 verify step; at the serving
+cell's 64 slots and 256-entry tables, one grid step per slot) and flash prefill
 for a v5e chip described through ``jax.experimental.topologies`` — no chip
 needed, the TPU compiler is installed beside JAX — at qwen2-0.5b's widths
 (14 query / 2 KV heads, head_dim 64) and at 40 / 8 heads, head_dim 128, with
@@ -91,6 +92,44 @@ def test_paged_decode_compiles_for_v5e(one_chip, H, K, dh, Q):
         args,
     )
     assert "tpu_custom_call" in txt
+
+
+def _pallas_grids(jaxpr) -> list[tuple]:
+    """The grids of every ``pallas_call`` in ``jaxpr``, nested ones too."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(tuple(eqn.params["grid_mapping"].grid))
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", None)
+            if inner is not None:
+                out += _pallas_grids(getattr(inner, "jaxpr", inner))
+    return out
+
+
+@pytest.mark.parametrize("Q", [1, 4])
+@pytest.mark.parametrize("width", [64, 256])
+def test_paged_decode_compiles_for_v5e_at_serving_cell(one_chip, width, Q):
+    """At the qwen2-0.5b serving cell's shapes (64 slots, 14 / 2 heads,
+    head_dim 64, blocks of 16, the 24-layer stacked pool) the decode kernel
+    compiles for a v5e at a 64- and a 256-entry table, and its grid is one
+    step per slot whatever the table's width: the table entries are walked
+    inside the step, live ones only."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S, H, K, dh = 64, 14, 2, 64
+    pool = sds((24, 2049, BS, K * dh), jnp.bfloat16)
+    args = (sds((S, Q, H, dh), jnp.bfloat16), pool, pool,
+            sds((S, width), jnp.int32), sds((S,), jnp.int32),
+            sds((), jnp.int32))
+
+    def fn(q, kp, vp, t, n, lay):
+        return paged_attention_pallas(q, kp, vp, t, n, scale=dh ** -0.5,
+                                      layer=lay)
+
+    assert _pallas_grids(jax.make_jaxpr(fn)(*args).jaxpr) == [(S,)]
+    assert "tpu_custom_call" in _compiled_text(fn, args)
 
 
 @pytest.mark.parametrize("H,K,dh", WIDTHS)
